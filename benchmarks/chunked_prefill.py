@@ -45,10 +45,10 @@ def _run_one(model, params, mesh, ecfg_kw: Dict, wl_kw: Dict,
     with the lowest p95 ITL — timing claims should compare the modes'
     quiet-box behaviour, not whichever run a noisy host interrupted.
     Outputs must be identical across every repeat (asserted)."""
-    from repro.compat import use_mesh
+    from jax import set_mesh
     from repro.serving import ContinuousBatchingEngine, EngineConfig
 
-    with use_mesh(mesh):
+    with set_mesh(mesh):
         ecfg = EngineConfig(prefill_chunk_tokens=chunk, **ecfg_kw)
         engine = ContinuousBatchingEngine(model, params, ecfg)
         if chunk is not None and not engine.chunking:

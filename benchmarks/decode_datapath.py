@@ -89,7 +89,7 @@ def modeled_bytes(cfg, B, prompt_len, block_size) -> Dict[str, float]:
 def sweep(batches=(1, 4, 8, 16), prompt_len: int = 96,
           block_size: int = 16, seed: int = 0) -> Dict:
     import jax
-    from repro.compat import use_mesh
+    from jax import set_mesh
     from repro.configs import get_config, reduced
     from repro.launch.mesh import make_test_mesh
     from repro.models.model import init_params
@@ -101,7 +101,7 @@ def sweep(batches=(1, 4, 8, 16), prompt_len: int = 96,
     params = init_params(cfg, jax.random.PRNGKey(0))
     pool_tokens = 1 << 15
     rows: List[Dict] = []
-    with use_mesh(mesh):
+    with set_mesh(mesh):
         for B in batches:
             row: Dict = {"batch": B, "prompt_len": prompt_len}
             row.update(modeled_bytes(cfg, B, prompt_len, block_size))

@@ -15,7 +15,7 @@ from typing import Dict
 import jax
 import numpy as np
 
-from repro.compat import use_mesh
+from jax import set_mesh
 from repro.configs import get_config, reduced
 from repro.core.bca import BatchingConfigurationAdvisor
 from repro.core.perfmodel import ServingCurves
@@ -33,7 +33,7 @@ def measured_curves(batches=(1, 2, 4, 8), n_requests: int = 10,
     params = init_params(cfg, jax.random.PRNGKey(0))
     model = Model(cfg, rules)
     rows = []
-    with use_mesh(mesh):
+    with set_mesh(mesh):
         for mb in batches:
             ecfg = EngineConfig(max_batch=mb, block_size=16,
                                 kv_pool_tokens=1 << 14, max_model_len=160,

@@ -80,11 +80,11 @@ def _served(reqs) -> int:
 def _kill_pair(cfg, model, params, mesh, steps, *, mode: str, n: int,
                sampled: bool, kill_step: int, seed: int) -> Dict:
     """Fault-free vs kill-1-of-2 run of the same workload; compare."""
-    from repro.compat import use_mesh
+    from jax import set_mesh
     from repro.serving import FaultInjector, ReplicatedCluster
     from repro.serving.faults import FaultSpec
 
-    with use_mesh(mesh):
+    with set_mesh(mesh):
         base_cluster = ReplicatedCluster(
             [_engine(model, params, steps) for _ in range(2)], mode=mode)
         baseline = _wl(cfg, n, sampled=sampled)
@@ -123,10 +123,10 @@ def _kill_pair(cfg, model, params, mesh, steps, *, mode: str, n: int,
 
 def _overload(cfg, model, params, mesh, steps, *, n: int) -> Dict:
     """Oversubscribed bounded-queue cluster: degrade, never die."""
-    from repro.compat import use_mesh
+    from jax import set_mesh
     from repro.serving import ReplicatedCluster
 
-    with use_mesh(mesh):
+    with set_mesh(mesh):
         cluster = ReplicatedCluster(
             [_engine(model, params, steps, max_waiting=2, max_batch=2)
              for _ in range(2)],

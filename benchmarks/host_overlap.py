@@ -85,7 +85,7 @@ def bit_identity(model, params, steps, cfg, mesh, *, n: int,
                  out: int) -> Dict:
     """Every scenario the synchronous loop's tests pin down, replayed
     sync-vs-overlap on fresh engines; one differing token fails it."""
-    from repro.compat import use_mesh
+    from jax import set_mesh
     from repro.serving import (FaultInjector, FaultSpec, ReplicatedCluster,
                                SamplingParams, shared_prefix_workload)
 
@@ -94,7 +94,7 @@ def bit_identity(model, params, steps, cfg, mesh, *, n: int,
 
     def both(tag, wl_fn, preempt=False, **ecfg_kw):
         outs, preemptions = {}, {}
-        with use_mesh(mesh):
+        with set_mesh(mesh):
             for overlap in (False, True):
                 eng = _engine(model, params, steps, overlap=overlap,
                               **ecfg_kw)
@@ -130,7 +130,7 @@ def bit_identity(model, params, steps, cfg, mesh, *, n: int,
     # redrive regenerates on the survivor — compare overlapped fault run
     # against the synchronous fault run, same injection point
     outs = {}
-    with use_mesh(mesh):
+    with set_mesh(mesh):
         for overlap in (False, True):
             inj = FaultInjector([FaultSpec("kill", replica=1, step=4)])
             cluster = ReplicatedCluster(
@@ -193,13 +193,13 @@ def steady_state_gap(model, params, steps, cfg, mesh, *, batch: int,
     One warmup run absorbs census lowering + jit compiles; best of
     ``repeats`` measured runs (standard noise policy here), escalating
     with more runs when borderline."""
-    from repro.compat import use_mesh
+    from jax import set_mesh
     from repro.serving import Observability
     from repro.serving.obs.series import BoundedSeries
 
     obs = Observability()
     runs: List[Dict] = []
-    with use_mesh(mesh):
+    with set_mesh(mesh):
         eng = _bench_engine(model, params, steps, batch=batch, overlap=True)
         obs.attach(eng)
         eng.run(_bench_wl(cfg, batch, out))                     # warmup
@@ -300,14 +300,14 @@ def throughput(model, params, steps, cfg, mesh, *, batch: int, out: int,
     """
     import statistics
 
-    from repro.compat import use_mesh
+    from jax import set_mesh
     from repro.serving import Observability
     from repro.serving.obs.series import BoundedSeries
 
     obs = Observability()
 
     def once(overlap: bool) -> Dict:
-        with use_mesh(mesh):
+        with set_mesh(mesh):
             eng = _bench_engine(model, params, steps, batch=batch,
                                 overlap=overlap)
             obs.attach(eng)

@@ -78,11 +78,11 @@ def exact_accounting(model, params, steps, cfg, mesh, *, n: int,
     """Every audited step: used + block_pad + prefix_held + free must
     equal pool_bytes exactly. Run with the prefix cache enabled so the
     prefix_held term is exercised, not just trivially zero."""
-    from repro.compat import use_mesh
+    from jax import set_mesh
     from repro.serving import Observability
 
     obs = Observability(audit_memory=True, windows=True)
-    with use_mesh(mesh):
+    with set_mesh(mesh):
         eng = _engine(model, params, steps, prefix_cache=True)
         obs.attach(eng)
         eng.run(_wl(cfg, n, out))
@@ -109,7 +109,7 @@ def reserved_unused(model, params, steps, cfg, mesh, *, n: int,
     calls (not run-to-completion) so the audit window is the steady
     in-flight state, not the tail where budgets are nearly consumed."""
     import numpy as np
-    from repro.compat import use_mesh
+    from jax import set_mesh
     from repro.core.bca import audit_sizing
     from repro.core.hardware import TPU_V5E
     from repro.serving import Observability, Request
@@ -118,7 +118,7 @@ def reserved_unused(model, params, steps, cfg, mesh, *, n: int,
     reqs = [Request(i, rng.integers(0, cfg.vocab_size, size=12),
                     max_new_tokens=budget) for i in range(n)]
     obs = Observability(audit_memory=True)
-    with use_mesh(mesh):
+    with set_mesh(mesh):
         eng = _engine(model, params, steps, max_model_len=512,
                       kv_pool_tokens=8192)
         obs.attach(eng)
@@ -186,8 +186,8 @@ def slo_response() -> Dict:
 
 # ------------------------------------------------------------- overhead --
 def _run_once(model, params, steps, cfg, mesh, n, out, obs=None) -> float:
-    from repro.compat import use_mesh
-    with use_mesh(mesh):
+    from jax import set_mesh
+    with set_mesh(mesh):
         eng = _engine(model, params, steps)
         if obs is not None:
             obs.attach(eng)

@@ -72,8 +72,8 @@ def _wl(cfg, n: int, out: int):
 # ------------------------------------------------------------- overhead --
 def _run_once(model, params, steps, cfg, mesh, n, out, obs=None) -> Dict:
     """One batch run; returns its median/mean decode-step latency."""
-    from repro.compat import use_mesh
-    with use_mesh(mesh):
+    from jax import set_mesh
+    with set_mesh(mesh):
         eng = _engine(model, params, steps)
         if obs is not None:
             obs.attach(eng)
@@ -139,7 +139,7 @@ def live_vs_offline(model, params, steps, cfg, mesh, *, n: int,
     the same workload. Every decode bucket the live observer censused
     must match an offline census within 10%."""
     import jax
-    from repro.compat import use_mesh
+    from jax import set_mesh
     from repro.core.analysis import HloCensus
     from repro.core.hardware import TPU_V5E
     from repro.core.roofline import roofline_report
@@ -158,12 +158,12 @@ def live_vs_offline(model, params, steps, cfg, mesh, *, n: int,
         specs.setdefault(key, spec)
         return orig(*args)
 
-    with use_mesh(mesh):
+    with set_mesh(mesh):
         eng = _engine(model, params, steps)
         eng._paged_jit = capturing
         eng.run(_wl(cfg, n, out))
     offline = {}
-    with use_mesh(mesh):
+    with set_mesh(mesh):
         for key, spec in specs.items():
             hlo = orig.lower(*spec).compile().as_text()
             c = HloCensus(hlo).census()
@@ -220,9 +220,9 @@ def trace_and_export(model, params, steps, cfg, mesh, *, n: int,
     from repro.serving import (Observability, lint_prometheus,
                                metrics_from_json, metrics_to_json,
                                prometheus_text, validate_chrome_trace)
-    from repro.compat import use_mesh
+    from jax import set_mesh
     obs = Observability()
-    with use_mesh(mesh):
+    with set_mesh(mesh):
         eng = _engine(model, params, steps)
         obs.attach(eng)
         m = eng.run(_wl(cfg, n, out))

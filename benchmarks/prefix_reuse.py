@@ -35,7 +35,7 @@ def run_pair(n_tenants: int = 4, per_tenant: int = 32,
              block_size: int = 16, kv_pool_tokens: int = 16384,
              seed: int = 0) -> Dict:
     import jax
-    from repro.compat import use_mesh
+    from jax import set_mesh
     from repro.configs import get_config, reduced
     from repro.launch.mesh import make_test_mesh
     from repro.models.model import Model, init_params
@@ -57,7 +57,7 @@ def run_pair(n_tenants: int = 4, per_tenant: int = 32,
         "max_batch": max_batch, "block_size": block_size,
         "kv_pool_tokens": kv_pool_tokens}}
     tokens: Dict[bool, list] = {}
-    with use_mesh(mesh):
+    with set_mesh(mesh):
         for cache_on in (False, True):
             ecfg = EngineConfig(
                 max_batch=max_batch, block_size=block_size,

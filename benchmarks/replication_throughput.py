@@ -34,7 +34,8 @@ sys.path.insert(0, "src")
 
 import jax                                                         # noqa: E402
 
-from repro.compat import make_mesh, use_mesh                       # noqa: E402
+from jax import set_mesh                                           # noqa: E402
+from repro.launch.mesh import make_mesh                            # noqa: E402
 from repro.configs import get_config, reduced                      # noqa: E402
 from repro.core.hardware import TPU_V5E                            # noqa: E402
 from repro.core.replication import slice_mesh                      # noqa: E402
@@ -93,7 +94,7 @@ def main():
         return ContinuousBatchingEngine(slice_model, slice_params,
                                         ecfg(b, slice_pool), steps=steps)
 
-    with use_mesh(slice0):
+    with set_mesh(slice0):
         curves = measure_curves(
             make_engine, lambda: workload(args.curve_requests, args.seed + 1),
             batches)
@@ -109,7 +110,7 @@ def main():
     b_max = max(args.kv_budget // args.max_model_len, 1)
     single = ContinuousBatchingEngine(Model(cfg, rules_for(full_mesh)),
                                       params, ecfg(b_max, args.kv_budget))
-    with use_mesh(full_mesh):
+    with set_mesh(full_mesh):
         single.run(workload(args.requests, args.seed))  # warmup/compile
         single.reset_stats()
         m_single = single.run(workload(args.requests, args.seed))

@@ -80,7 +80,6 @@ def _perf_workload(cfg, *, max_new):
 
 def _make_engine(model, params, ecfg_kw: Dict, *, speculate: bool,
                  audit: bool = False):
-    from repro.core import H100_PAPER
     from repro.serving import (ContinuousBatchingEngine, EngineConfig,
                                Observability)
 
@@ -91,7 +90,7 @@ def _make_engine(model, params, ecfg_kw: Dict, *, speculate: bool,
                            f"{eng.spec_disabled_reason}")
     obs = None
     if audit:
-        obs = Observability(hw=H100_PAPER, audit_memory=True)
+        obs = Observability(audit_memory=True)
         obs.attach_backend(eng)
     return eng, obs
 
@@ -140,9 +139,9 @@ def _run_one(model, params, mesh, ecfg_kw: Dict, make_reqs, *,
     """Warm up (compiles all decode/verify buckets), then measure
     ``repeats`` runs and keep the fastest — outputs must be identical
     across repeats (asserted)."""
-    from repro.compat import use_mesh
+    from jax import set_mesh
 
-    with use_mesh(mesh):
+    with set_mesh(mesh):
         eng, obs = _make_engine(model, params, ecfg_kw,
                                 speculate=speculate, audit=audit)
         total_blocks = eng.pool.manager.free_blocks
@@ -169,9 +168,9 @@ def _perf_pair(model, params, mesh, ecfg_kw: Dict, make_reqs,
     both sides equally instead of biasing whichever ran second. Each
     side keeps its best wall; outputs must match across sides and
     repeats (the perf run doubles as an identity check)."""
-    from repro.compat import use_mesh
+    from jax import set_mesh
 
-    with use_mesh(mesh):
+    with set_mesh(mesh):
         engines = {}
         for spec in (False, True):
             eng, _ = _make_engine(model, params, ecfg_kw, speculate=spec)

@@ -56,11 +56,11 @@ def _throughput_pair(cfg, model, params, mesh, *, n=12, mean_in=24,
                      mean_out=24) -> Dict:
     """Same workload greedy vs sampled (fresh engine each, one warmup run
     so compiles never pollute the timing)."""
-    from repro.compat import use_mesh
+    from jax import set_mesh
     from repro.serving import SamplingParams, sharegpt_like
 
     out: Dict = {}
-    with use_mesh(mesh):
+    with set_mesh(mesh):
         for tag, sampling in (
                 ("greedy", None),
                 ("sampled", SamplingParams(temperature=0.8, top_k=40,
@@ -85,13 +85,13 @@ def _throughput_pair(cfg, model, params, mesh, *, n=12, mean_in=24,
 def _abort_reclaim(cfg, model, params, mesh) -> Dict:
     """Abort mid-decode and mid-prefill; measure reclaim latency and
     verify the pool free-count is restored exactly."""
-    from repro.compat import use_mesh
+    from jax import set_mesh
     from repro.serving import SamplingParams, ServingAPI
     import numpy as np
 
     rng = np.random.default_rng(0)
     out: Dict = {}
-    with use_mesh(mesh):
+    with set_mesh(mesh):
         # --- mid-decode abort (plain engine) ---
         eng = _engine(model, params)
         api = ServingAPI(eng)
@@ -134,7 +134,7 @@ def _abort_reclaim(cfg, model, params, mesh) -> Dict:
 
 def _stream_equals_run(cfg, model, params, mesh, *, n=6) -> bool:
     """submit/stream/drain must produce the same tokens as batch run()."""
-    from repro.compat import use_mesh
+    from jax import set_mesh
     from repro.serving import SamplingParams, sharegpt_like
 
     sp = SamplingParams(temperature=0.6, top_p=0.9, seed=11)
@@ -142,7 +142,7 @@ def _stream_equals_run(cfg, model, params, mesh, *, n=6) -> bool:
                                mean_in=16, mean_out=10, max_len=64,
                                sigma=0.3, sampling=sp)
     from repro.serving import ServingAPI
-    with use_mesh(mesh):
+    with set_mesh(mesh):
         eng = _engine(model, params, max_batch=4)
         reqs = wl()
         eng.run(reqs)

@@ -11,7 +11,7 @@ import jax.numpy as jnp
 
 sys.path.insert(0, "src")
 
-from repro.compat import use_mesh
+from jax import set_mesh
 from repro.configs import get_config, reduced                     # noqa: E402
 from repro.core import TPU_V5E, decode_step_terms                 # noqa: E402
 from repro.launch.mesh import make_test_mesh                      # noqa: E402
@@ -32,7 +32,7 @@ def main():
           f"d={full.d_model} params={full.num_params()/1e9:.2f}B "
           f"(active {full.active_params()/1e9:.2f}B)")
 
-    with use_mesh(mesh):
+    with set_mesh(mesh):
         params = M.init_params(cfg, jax.random.PRNGKey(0))
         tok = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0,
                                  cfg.vocab_size)

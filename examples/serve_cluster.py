@@ -20,7 +20,8 @@ sys.path.insert(0, "src")
 
 import jax                                                         # noqa: E402
 
-from repro.compat import make_mesh, use_mesh                       # noqa: E402
+from jax import set_mesh                                           # noqa: E402
+from repro.launch.mesh import make_mesh                            # noqa: E402
 from repro.configs import get_config, reduced                      # noqa: E402
 from repro.core.hardware import TPU_V5E                            # noqa: E402
 from repro.models.model import Model, init_params                  # noqa: E402
@@ -68,7 +69,7 @@ def main():
     n_rep, max_batch = args.replicas, args.max_batch
     if args.autoscale:
         model = Model(cfg, rules_for(mesh))
-        with use_mesh(mesh):
+        with set_mesh(mesh):
             decision = autoscale(
                 lambda b: ContinuousBatchingEngine(model, params, ecfg(b)),
                 lambda: workload(1), batches=(1, 2), hw=TPU_V5E,

@@ -10,7 +10,7 @@ sys.path.insert(0, "src")
 
 import jax                                                         # noqa: E402
 
-from repro.compat import use_mesh
+from jax import set_mesh
 from repro.configs import get_config, reduced                      # noqa: E402
 from repro.launch.mesh import make_test_mesh                       # noqa: E402
 from repro.models.model import Model, init_params                  # noqa: E402
@@ -25,7 +25,7 @@ def main():
     rules = rules_for(mesh)
     params = init_params(cfg, jax.random.PRNGKey(0))
     model = Model(cfg, rules)
-    with use_mesh(mesh):
+    with set_mesh(mesh):
         for mb in (1, 4, 8):
             ecfg = EngineConfig(max_batch=mb, block_size=16,
                                 kv_pool_tokens=1 << 14, max_model_len=128,

@@ -12,7 +12,7 @@ sys.path.insert(0, "src")
 
 import jax                                                         # noqa: E402
 
-from repro.compat import use_mesh                                  # noqa: E402
+from jax import set_mesh                                           # noqa: E402
 from repro.configs import get_config, reduced                      # noqa: E402
 from repro.core import H100_PAPER, speculation_advisor             # noqa: E402
 from repro.launch.mesh import make_test_mesh                       # noqa: E402
@@ -32,7 +32,7 @@ def main():
     params = init_params(cfg, jax.random.PRNGKey(0))
     model = Model(cfg, rules)
     outs = {}
-    with use_mesh(mesh):
+    with set_mesh(mesh):
         for spec in (False, True):
             ecfg = EngineConfig(max_batch=4, block_size=8,
                                 kv_pool_tokens=1 << 13, max_model_len=256,

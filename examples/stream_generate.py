@@ -15,7 +15,7 @@ sys.path.insert(0, "src")
 import jax                                                         # noqa: E402
 import numpy as np                                                 # noqa: E402
 
-from repro.compat import use_mesh                                  # noqa: E402
+from jax import set_mesh                                           # noqa: E402
 from repro.configs import get_config, reduced                      # noqa: E402
 from repro.launch.mesh import make_test_mesh                       # noqa: E402
 from repro.models.model import Model, init_params                  # noqa: E402
@@ -33,7 +33,7 @@ def main():
     rng = np.random.default_rng(0)
     prompt = lambda n: rng.integers(0, cfg.vocab_size, n)   # noqa: E731
 
-    with use_mesh(mesh):
+    with set_mesh(mesh):
         ecfg = EngineConfig(max_batch=4, block_size=16,
                             kv_pool_tokens=1 << 13, max_model_len=128,
                             prefill_bucket=32)

@@ -14,7 +14,7 @@ sys.path.insert(0, "src")
 import jax                                                         # noqa: E402
 import jax.numpy as jnp                                            # noqa: E402
 
-from repro.compat import use_mesh
+from jax import set_mesh
 from repro.configs import get_config                               # noqa: E402
 from repro.launch.mesh import make_test_mesh                       # noqa: E402
 from repro.models.model import init_params                         # noqa: E402
@@ -48,7 +48,7 @@ def main():
     data = synthetic_batches(cfg, batch=args.batch, seq=args.seq)
 
     t0 = time.time()
-    with use_mesh(mesh):
+    with set_mesh(mesh):
         for i in range(args.steps):
             batch = {k: jnp.asarray(v) for k, v in next(data).items()}
             params, opt_state, m = step(params, opt_state, batch)

@@ -1,7 +1,8 @@
 """The paper's contribution as a first-class feature: GPU/TPU-level
 bottleneck analysis (HLO census + roofline), the Batching Configuration
 Advisor (Eq. 2), and the replication planner + co-location simulator."""
-from repro.core.hardware import Hardware, TPU_V5E, H100_PAPER, HARDWARE  # noqa
+from repro.core.hardware import (Hardware, TPU_V5E, H100_PAPER, HARDWARE,  # noqa
+                                 hardware_for, local_hardware)
 from repro.core.analysis import HloCensus, OpCensus, census_from_compiled, memory_from_compiled  # noqa
 from repro.core.roofline import RooflineReport, roofline_report, model_flops_for  # noqa
 from repro.core.perfmodel import (HostOverhead, decode_step_terms,  # noqa

@@ -1,8 +1,10 @@
 """Hardware descriptions for roofline analysis.
 
-TPU v5e is the deployment target (constants from the assignment);
-the H100 entry carries the paper's own roofline constants (Table II /
-Fig. 1) and is used to reproduce the paper's measured numbers.
+TPU v5e is the deployment target. The H100 entry carries the paper's own
+roofline constants (Table II / Fig. 1) and is used only to reproduce the
+paper's analytic numbers. A process on an accelerator takes its peaks
+from :func:`hardware_for` by ``device_kind``; a kind missing from
+``PEAKS_BY_DEVICE_KIND`` is an error, not a default.
 """
 from __future__ import annotations
 
@@ -24,6 +26,8 @@ class Hardware:
     plot_bw_ceiling: float = 0.0
 
 
+# Published peaks of one TPU v5e chip (Google Cloud documentation,
+# "TPU v5e"): 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s ICI.
 TPU_V5E = Hardware(
     name="tpu-v5e",
     peak_flops=197e12,         # bf16
@@ -50,3 +54,25 @@ H100_PAPER = Hardware(
 )
 
 HARDWARE = {h.name: h for h in (TPU_V5E, H100_PAPER)}
+
+# ``jax.Device.device_kind`` -> peaks. JAX names a v5e chip "TPU v5 lite".
+PEAKS_BY_DEVICE_KIND = {"TPU v5 lite": TPU_V5E}
+
+
+def hardware_for(device_kind: str) -> Hardware:
+    """Published peaks of an accelerator, by its ``device_kind``."""
+    try:
+        return PEAKS_BY_DEVICE_KIND[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device_kind {device_kind!r}; add them "
+            f"to PEAKS_BY_DEVICE_KIND with their source") from None
+
+
+def local_hardware() -> Hardware:
+    """Peaks of the device this process computes on. The CPU backend
+    (tests, CPU rehearsals) has no peaks of its own: roofline estimates
+    there are modelled for the v5e deployment target."""
+    import jax
+    dev = jax.devices()[0]
+    return TPU_V5E if dev.platform == "cpu" else hardware_for(dev.device_kind)

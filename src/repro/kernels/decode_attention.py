@@ -19,7 +19,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from repro import compat
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -97,8 +96,7 @@ def gqa_decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((G, 1), jnp.float32),     # running denom l
             pltpu.VMEM((G, hd), jnp.float32),    # output accumulator
         ],
-        compiler_params=compat.pallas_tpu_compiler_params(
-            pltpu,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(lengths2d, qg, k, v)

@@ -33,7 +33,7 @@ corrupting live state.
 """
 from __future__ import annotations
 
-import dataclasses
+import functools
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -268,9 +268,8 @@ def cache_layout(cfg: ArchConfig, block_size: int):
 
     ``is_kv``: True for paged attention-K/V leaves (vs dense per-slot
     state); ``bdim``: index of the block/slot axis (1 when the leaf is
-    layer-stacked). Shared by :class:`PagedKVCache` and the engine's
-    jitted fused chunk-prefill step (which re-implements gather/scatter
-    inside the jit and needs the same layout facts at trace time).
+    layer-stacked). Every program that reads or writes the pool takes
+    these facts from here at trace time.
     """
     template = model_lib.abstract_cache(cfg, 1, block_size)
     is_spec = lambda x: isinstance(x, ParamSpec)    # noqa: E731
@@ -281,59 +280,173 @@ def cache_layout(cfg: ArchConfig, block_size: int):
     return is_kv, bdim
 
 
-def gather_prefix_jit(pool, is_kv, bdim, tables, block_size: int):
-    """In-jit analogue of :meth:`PagedKVCache.gather_prefix`: materialize
-    dense ``[.., 1, P, K, hd]`` prefix K/V from the pool leaves through a
-    trash-padded ``[nb]`` block table (rows past the valid prefix length
-    are masked downstream via ``prefix_len``). Traced — runs fused inside
-    the chunk-prefill jit instead of as per-leaf eager dispatches."""
+def _pow2(n: int) -> int:
+    return 1 << max(n - 1, 0).bit_length()
+
+
+def _rows(x: jax.Array) -> jax.Array:
+    """``[..., K, hd]`` -> the pool's lane-dense ``[..., K*hd]`` rows."""
+    return x.reshape(*x.shape[:-2], x.shape[-2] * x.shape[-1])
+
+
+# A layer-stacked K/V leaf [L, N, BS, K*hd] is indexed as its flat
+# [L*N, BS, K*hd] form with per-layer offsets, so gathers and scatters
+# address its leading dims — as the decode step does. Indexing behind the
+# layer dim makes the TPU compiler relayout the whole leaf into a copy,
+# which a pool that fills device memory has no room for.
+def _layer_ids(leaf, ids):
+    """Flat ids of physical blocks ``ids`` in every layer: [L, *ids]."""
+    L, N = leaf.shape[:2]
+    return (jnp.arange(L) * N).reshape((L,) + (1,) * ids.ndim) + ids
+
+
+def _flat(leaf):
+    return leaf.reshape(-1, *leaf.shape[2:])
+
+
+def _take_blocks(leaf, bd: int, ids):
+    """``leaf[(:,) ids]``: [(L,) *ids.shape, BS, K*hd]."""
+    return _flat(leaf)[_layer_ids(leaf, ids)] if bd == 1 else leaf[ids]
+
+
+def _set_rows(leaf, bd: int, phys, sib, rows):
+    """``leaf[(:,) phys, sib] = rows`` with rows ``[(L,) n, K*hd]``."""
+    if bd == 0:
+        return leaf.at[phys, sib].set(rows)
+    ids = _layer_ids(leaf, phys)
+    flat = _flat(leaf).at[ids, jnp.broadcast_to(sib, ids.shape)].set(rows)
+    return flat.reshape(leaf.shape)
+
+
+def gather_prefix_jit(pool, cfg: ArchConfig, tables, block_size: int):
+    """Materialize dense ``[.., 1, P, K, hd]`` prefix K/V from the pool
+    leaves through a trash-padded ``[nb]`` block table (rows past the
+    valid prefix length are masked downstream via ``prefix_len``).
+    Traceable: it runs fused inside the chunk-prefill jit."""
+    is_kv, bdim = cache_layout(cfg, block_size)
     P = tables.shape[0] * block_size
+    heads = (cfg.n_kv_heads, cfg.hd)
 
     def g(leaf, kv, bd):
         if not kv:
             raise NotImplementedError(
                 "prefix gather over non-KV (dense-state) leaves: chunked "
                 "prefill requires per-token state")
-        if bd == 1:                                # [L, NB, BS, K, hd]
-            v = leaf[:, tables]
-            return v.reshape(v.shape[0], 1, P, *v.shape[3:])
-        v = leaf[tables]
-        return v.reshape(1, P, *v.shape[2:])
+        v = _take_blocks(leaf, bd, tables)         # [(L,) nb, BS, K*hd]
+        return v.reshape(*v.shape[:-3], 1, P, *heads)
 
     return jax.tree.map(g, pool, is_kv, bdim)
 
 
-def scatter_chunk_jit(pool, cache_one, is_kv, bdim, tables, start, n_valid,
-                      block_size: int):
-    """In-jit analogue of the token-granular prefill write: scatter the
-    chunk cache's first ``n_valid`` rows (traced) to their physical
-    (block, slot) addresses starting at traced position ``start``;
-    padding rows are routed to the trash block. Returns the new pool."""
+def scatter_rows_jit(pool, cache_one, cfg: ArchConfig, tables, start,
+                     n_valid, block_size: int, slot=None):
+    """Scatter the batch-1 cache's first ``n_valid`` rows (traced) to
+    their physical (block, slot) addresses starting at traced position
+    ``start`` through the request's trash-padded block table; padding
+    rows are routed to the trash block. Dense-state leaves are written
+    whole at ``slot`` (None where the caller guarantees there are none).
+    Traceable; returns the new pool."""
+    is_kv, bdim = cache_layout(cfg, block_size)
+
     def s(leaf, view, kv, bd):
-        if not kv:
-            raise NotImplementedError(
-                "chunk scatter over non-KV (dense-state) leaves: chunked "
-                "prefill requires per-token state")
         v = view[:, 0] if bd == 1 else view[0]     # [L, S, K, hd] / [S,..]
+        if not kv:
+            if slot is None:
+                raise NotImplementedError(
+                    "chunk scatter over non-KV (dense-state) leaves: "
+                    "chunked prefill requires per-token state")
+            return leaf.at[:, slot].set(v) if bd == 1 else leaf.at[slot].set(v)
         S = v.shape[1] if bd == 1 else v.shape[0]
         trash = (leaf.shape[1] if bd == 1 else leaf.shape[0]) - 1
         pos = start + jnp.arange(S)
         idx = jnp.clip(pos // block_size, 0, tables.shape[0] - 1)
         phys = jnp.where(jnp.arange(S) < n_valid, tables[idx], trash)
-        sib = pos % block_size
-        if bd == 1:
-            return leaf.at[:, phys, sib].set(v)
-        return leaf.at[phys, sib].set(v)
+        return _set_rows(leaf, bd, phys, pos % block_size, _rows(v))
 
     return jax.tree.map(s, pool, cache_one, is_kv, bdim)
+
+
+# Reads of the pool run as programs too: an eager reshape or index of a
+# pool leaf would materialize a copy of it.
+_gather_prefix = jax.jit(gather_prefix_jit,
+                         static_argnames=("cfg", "block_size"))
+
+
+@functools.partial(jax.jit, static_argnames=("cfg", "block_size"))
+def _gather(pool, tables, slots, *, cfg: ArchConfig, block_size: int):
+    """Dense ``[(L,) B, S_pad, ...]`` cache view of the requests whose
+    block tables are the rows of ``tables`` (dense state: their slots)."""
+    is_kv, bdim = cache_layout(cfg, block_size)
+    B, nb = tables.shape
+    heads = (cfg.n_kv_heads, cfg.hd)
+
+    def g(leaf, kv, bd):
+        if kv:
+            v = _take_blocks(leaf, bd, tables)     # [(L,) B, nb, BS, K*hd]
+            return v.reshape(*v.shape[:-4], B, nb * block_size, *heads)
+        return jnp.take(leaf, slots, axis=bd)
+
+    return jax.tree.map(g, pool, is_kv, bdim)
+
+
+# Every program that mutates the pool donates it (argument 0), so the
+# update aliases the pool's buffers: no program ever holds two copies of
+# a pool that fills device memory.
+_donating = functools.partial(jax.jit, static_argnames=("cfg", "block_size"),
+                              donate_argnums=0)
+
+
+@_donating
+def _write_rows(pool, cache_one, tables, start, n_valid, slot, *,
+                cfg: ArchConfig, block_size: int):
+    return scatter_rows_jit(pool, cache_one, cfg, tables, start, n_valid,
+                            block_size, slot=slot)
+
+
+@_donating
+def _write_new_tokens(pool, new_cache, phys, sib, pos, slots, *,
+                      cfg: ArchConfig, block_size: int):
+    """Gather-mode write-back: row ``pos[i]`` of request i's dense view
+    goes to physical (phys[i], sib[i]); dense state goes to its slot."""
+    is_kv, bdim = cache_layout(cfg, block_size)
+    rows = jnp.arange(phys.shape[0])
+
+    def s(leaf, view, kv, bd):
+        if kv:
+            row = view[:, rows, pos] if bd == 1 else view[rows, pos]
+            return _set_rows(leaf, bd, phys, sib, _rows(row))
+        return leaf.at[:, slots].set(view) if bd == 1 \
+            else leaf.at[slots].set(view)
+
+    return jax.tree.map(s, pool, new_cache, is_kv, bdim)
+
+
+@_donating
+def _copy_block(pool, old, new, *, cfg: ArchConfig, block_size: int):
+    """Copy physical block ``old`` into ``new`` in every K/V leaf."""
+    is_kv, bdim = cache_layout(cfg, block_size)
+
+    def cp(leaf, kv, bd):
+        if not kv:
+            return leaf
+        if bd == 1:
+            return _flat(leaf).at[_layer_ids(leaf, new)].set(
+                _flat(leaf)[_layer_ids(leaf, old)]).reshape(leaf.shape)
+        return leaf.at[new].set(leaf[old])
+
+    return jax.tree.map(cp, pool, is_kv, bdim)
 
 
 class PagedKVCache:
     """Physical paged pool mirroring a model cache pytree."""
 
     def __init__(self, cfg: ArchConfig, *, num_blocks: int, block_size: int,
-                 max_batch: int):
+                 max_batch: int,
+                 sharding: Optional[jax.sharding.Sharding] = None):
         self.cfg = cfg
+        # where the pool and every array uploaded for it live (None: the
+        # default device); a replica passes its own device's sharding
+        self.sharding = sharding
         self.block_size = block_size
         self.num_blocks = num_blocks
         self.max_batch = max_batch
@@ -355,10 +468,16 @@ class PagedKVCache:
             shape = list(spec.shape)
             # +1: trash block / trash slot absorbing padding-row writes
             shape[bdim] = num_blocks + 1 if is_kv else max_batch + 1
+            if is_kv:       # [.., BS, K, hd] -> lane-dense [.., BS, K*hd]
+                shape[-2:] = [shape[-2] * shape[-1]]
             return jnp.zeros(tuple(shape), spec.dtype)
 
-        self.pool = jax.tree.map(mk, template, self._is_kv, self._bdim,
-                                 is_leaf=is_spec)
+        # zeros made by a program that runs on the pool's own device:
+        # jnp.zeros(device=...) fills on the default device first and
+        # copies, which a pool sized to another chip's memory overflows
+        self.pool = jax.jit(lambda: jax.tree.map(
+            mk, template, self._is_kv, self._bdim, is_leaf=is_spec),
+            out_shardings=sharding)()
         # device block-table cache for the zero-copy view
         self._dev_tables: Optional[jax.Array] = None
         self._dev_slots: Optional[jax.Array] = None
@@ -403,22 +522,9 @@ class PagedKVCache:
         for i, rid in enumerate(req_ids):
             blocks = self.manager.tables.get(rid, [])
             table[i, :len(blocks)] = blocks[:pad_blocks]
-        tbl = jnp.asarray(table)
-        slots = jnp.asarray([self._slot(rid) for rid in req_ids])
-
-        def g(pool, is_kv, bdim):
-            if is_kv:
-                if bdim == 1:        # [L, NB, BS, K, hd]
-                    v = pool[:, tbl]                      # [L,B,nb,BS,K,hd]
-                    L = v.shape[0]
-                    return v.reshape(L, B, pad_blocks * self.block_size,
-                                     *v.shape[4:])
-                v = pool[tbl]                             # [B,nb,BS,K,hd]
-                return v.reshape(B, pad_blocks * self.block_size,
-                                 *v.shape[3:])
-            return jnp.take(pool, slots, axis=bdim)
-
-        return jax.tree.map(g, self.pool, self._is_kv, self._bdim)
+        slots = np.asarray([self._slot(rid) for rid in req_ids], np.int32)
+        return _gather(self.pool, *self.put((table, slots)), cfg=self.cfg,
+                       block_size=self.block_size)
 
     def scatter_new_token(self, req_ids: Sequence[int],
                           positions: Sequence[int], new_cache):
@@ -430,25 +536,11 @@ class PagedKVCache:
             blocks = self.manager.tables[rid]
             phys[i] = blocks[pos // self.block_size]
             slot_in_block[i] = pos % self.block_size
-        phys_j = jnp.asarray(phys)
-        sib_j = jnp.asarray(slot_in_block)
-        pos_j = jnp.asarray(np.asarray(positions, np.int32))
-        slots = jnp.asarray([self._slot(rid) for rid in req_ids])
-        barange = jnp.arange(B)
-
-        def s(pool, view, is_kv, bdim):
-            if is_kv:
-                if bdim == 1:
-                    row = view[:, barange, pos_j]          # [L,B,K,hd]
-                    return pool.at[:, phys_j, sib_j].set(row)
-                row = view[barange, pos_j]
-                return pool.at[phys_j, sib_j].set(row)
-            if bdim == 1:
-                return pool.at[:, slots].set(view)
-            return pool.at[slots].set(view)
-
-        self.pool = jax.tree.map(s, self.pool, new_cache, self._is_kv,
-                                 self._bdim)
+        self.pool = _write_new_tokens(
+            self.pool, new_cache, phys, slot_in_block,
+            np.asarray(positions, np.int32),
+            np.asarray([self._slot(rid) for rid in req_ids], np.int32),
+            cfg=self.cfg, block_size=self.block_size)
 
     def gather_prefix(self, blocks: Sequence[int], nb_pad: int):
         """Materialize cached prefix K/V for a suffix-only prefill.
@@ -463,21 +555,8 @@ class PagedKVCache:
         """
         table = np.full((nb_pad,), self.trash_block, np.int32)
         table[:len(blocks)] = blocks
-        tbl = jnp.asarray(table)
-        P = nb_pad * self.block_size
-
-        def g(pool, is_kv, bdim):
-            if not is_kv:
-                raise NotImplementedError(
-                    "prefix gather over non-KV (dense-state) leaves: "
-                    "prefix caching requires per-token state")
-            if bdim == 1:                          # [L, NB, BS, K, hd]
-                v = pool[:, tbl]                   # [L, nb, BS, K, hd]
-                return v.reshape(v.shape[0], 1, P, *v.shape[3:])
-            v = pool[tbl]                          # [nb, BS, K, hd]
-            return v.reshape(1, P, *v.shape[2:])
-
-        return jax.tree.map(g, self.pool, self._is_kv, self._bdim)
+        return _gather_prefix(self.pool, self.cfg, self.put(table),
+                              self.block_size)
 
     def ensure_writable(self, req_id: int, pos: int):
         """Copy-on-write fork of the block holding ``pos`` if it is shared.
@@ -492,15 +571,8 @@ class PagedKVCache:
         if not self.manager.needs_cow(req_id, pos):
             return
         old, new = self.manager.copy_on_write(req_id, idx)
-
-        def cp(pool, is_kv, bdim):
-            if not is_kv:
-                return pool
-            if bdim == 1:
-                return pool.at[:, new].set(pool[:, old])
-            return pool.at[new].set(pool[old])
-
-        self.pool = jax.tree.map(cp, self.pool, self._is_kv, self._bdim)
+        self.pool = _copy_block(self.pool, np.int32(old), np.int32(new),
+                                cfg=self.cfg, block_size=self.block_size)
 
     def write_prefill(self, req_id: int, cache_one, start_pos: int = 0,
                       n_tokens: Optional[int] = None):
@@ -519,73 +591,34 @@ class PagedKVCache:
         table (a chunk must extend the table first, through the
         watermark-checked :meth:`BlockManager.extend`).
         """
-        if n_tokens is not None:
-            return self._write_token_range(req_id, cache_one, start_pos,
-                                           n_tokens)
-        if start_pos % self.block_size:
-            raise ValueError(
-                f"start_pos ({start_pos}) must be block-aligned "
-                f"(block_size={self.block_size}); pass n_tokens for the "
-                f"token-granular chunk path")
-        blocks = self.manager.tables[req_id][start_pos // self.block_size:]
-        nb = len(blocks)
-        S_cap = nb * self.block_size
-        phys = jnp.asarray(blocks)
-        slot = self._slot(req_id)
-
-        def w(pool, view, is_kv, bdim):
-            if is_kv:
-                if bdim == 1:
-                    v = view[:, 0]                        # [L,S,K,hd]
-                    S = min(v.shape[1], S_cap)
-                    pad = S_cap - S
-                    v = jnp.pad(v[:, :S], ((0, 0), (0, pad), (0, 0), (0, 0)))
-                    v = v.reshape(v.shape[0], nb, self.block_size,
-                                  *v.shape[2:])
-                    return pool.at[:, phys].set(v)
-                v = view[0]
-                S = min(v.shape[0], S_cap)
-                pad = S_cap - S
-                v = jnp.pad(v[:S], ((0, pad), (0, 0), (0, 0)))
-                v = v.reshape(nb, self.block_size, *v.shape[1:])
-                return pool.at[phys].set(v)
-            if bdim == 1:
-                return pool.at[:, slot].set(view[:, 0])
-            return pool.at[slot].set(view[0])
-
-        self.pool = jax.tree.map(w, self.pool, cache_one, self._is_kv,
-                                 self._bdim)
-
-    def _write_token_range(self, req_id: int, cache_one, start_pos: int,
-                           n_tokens: int):
-        """Scatter ``n_tokens`` prefill rows at positions
-        ``[start_pos, start_pos + n_tokens)`` — the chunk write."""
-        if n_tokens < 1:
-            raise ValueError(f"n_tokens must be >= 1, got {n_tokens}")
         table = self.manager.tables.get(req_id, [])
-        end = start_pos + n_tokens
-        if end > len(table) * self.block_size:
+        cap = len(table) * self.block_size
+        slot = None             # dense state: whole-prompt writes only
+        if n_tokens is None:
+            if start_pos % self.block_size:
+                raise ValueError(
+                    f"start_pos ({start_pos}) must be block-aligned "
+                    f"(block_size={self.block_size}); pass n_tokens for the "
+                    f"token-granular chunk path")
+            # the view's rows up to the table's end
+            S = next((v.shape[-3] for v, kv in zip(
+                jax.tree.leaves(cache_one), jax.tree.leaves(self._is_kv))
+                if kv), 0)
+            n_tokens = min(S, cap - start_pos)
+            slot = np.int32(self._slot(req_id))
+        elif n_tokens < 1:
+            raise ValueError(f"n_tokens must be >= 1, got {n_tokens}")
+        elif start_pos + n_tokens > cap:
             raise ValueError(
-                f"chunk write [{start_pos}, {end}) over-allocates past "
-                f"req {req_id}'s table ({len(table)} blocks x "
-                f"{self.block_size}); extend() the table first")
-        pos = np.arange(start_pos, end)
-        phys_j = jnp.asarray(np.asarray(table, np.int32)
-                             [pos // self.block_size])
-        sib_j = jnp.asarray((pos % self.block_size).astype(np.int32))
-
-        def w(pool, view, is_kv, bdim):
-            if not is_kv:
-                raise NotImplementedError(
-                    "token-granular prefill writes over non-KV "
-                    "(dense-state) leaves: chunked prefill requires "
-                    "per-token state (the engine gates on it)")
-            if bdim == 1:                       # view [L, 1, S_pad, K, hd]
-                return pool.at[:, phys_j, sib_j].set(view[:, 0, :n_tokens])
-            return pool.at[phys_j, sib_j].set(view[0, :n_tokens])
-
-        self.pool = jax.tree.map(w, self.pool, cache_one, self._is_kv,
-                                 self._bdim)
+                f"chunk write [{start_pos}, {start_pos + n_tokens}) "
+                f"over-allocates past req {req_id}'s table ({len(table)} "
+                f"blocks x {self.block_size}); extend() the table first")
+        # pow2-padded table: one compiled write per (view width, pad)
+        tbl = np.full((_pow2(len(table)),), self.trash_block, np.int32)
+        tbl[:len(table)] = table
+        self.pool = _write_rows(
+            self.pool, cache_one, tbl, np.int32(start_pos),
+            np.int32(n_tokens), slot, cfg=self.cfg, block_size=self.block_size)
 
     # ------------------------------------------------------- zero-copy --
     def view(self, req_ids: Sequence[int], positions: Sequence[int],
@@ -637,7 +670,7 @@ class PagedKVCache:
                         snap[i] = blocks
                         changed = True
                 if changed:
-                    self._dev_tables = jnp.asarray(table)
+                    self._dev_tables = self.put(table)
             else:
                 table = np.full((batch_pad, nb_pad), self.trash_block,
                                 np.int32)
@@ -650,8 +683,7 @@ class PagedKVCache:
                     slots[i] = self._slot(rid)
                 self._tables_np = table
                 self._tables_snap = snap
-                self._dev_tables, self._dev_slots = \
-                    jax.device_put((table, slots))
+                self._dev_tables, self._dev_slots = self.put((table, slots))
             self._dev_tables_key = key
         pt = tuple(positions)
         cached = self._poslen
@@ -663,11 +695,15 @@ class PagedKVCache:
             pos[:B] = np.asarray(positions, np.int32)
             lens = np.zeros((batch_pad,), np.int32)
             lens[:B] = pos[:B] + 1
-            dev_pos, dev_lens = jax.device_put((pos, lens))
+            dev_pos, dev_lens = self.put((pos, lens))
         self._poslen = (ckey, pt, dev_pos, dev_lens)
         return PagedCacheView(self.pool, self._dev_tables,
                               dev_lens, dev_pos,
                               self._dev_slots, self.block_size)
+
+    def put(self, tree):
+        """Upload host arrays to the pool's device."""
+        return jax.device_put(tree, self.sharding)
 
     def commit(self, new_pool):
         """Adopt the pool pytree returned by a zero-copy decode step."""

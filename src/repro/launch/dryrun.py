@@ -18,7 +18,7 @@ import traceback
 
 import jax
 
-from repro.compat import use_mesh
+from jax import set_mesh
 from repro.configs import ASSIGNED
 from repro.core.analysis import (HloCensus, cpu_upcast_artifact_bytes,
                                  memory_from_compiled)
@@ -41,7 +41,7 @@ def run_case(arch: str, shape: str, multi_pod: bool, out_dir: str,
         moment = "bfloat16" if arch == "arctic-480b" else "float32"
         case = build_case(arch, shape, mesh, moment_dtype=moment,
                           variant=variant)
-        with use_mesh(mesh):
+        with set_mesh(mesh):
             jitted = jax.jit(case.fn, in_shardings=case.in_shardings,
                              out_shardings=case.out_shardings,
                              donate_argnums=case.donate)

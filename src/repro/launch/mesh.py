@@ -7,7 +7,15 @@ import so these meshes can be built on the CPU container.
 """
 from __future__ import annotations
 
-from repro.compat import make_mesh
+import jax
+
+
+def make_mesh(axis_shapes, axis_names):
+    """``jax.make_mesh`` with Auto axes: the model places activations
+    through ``ShardingRules`` constraints, not explicit-axis types."""
+    return jax.make_mesh(axis_shapes, axis_names,
+                         axis_types=(jax.sharding.AxisType.Auto,)
+                         * len(axis_names))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

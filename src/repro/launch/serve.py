@@ -13,6 +13,59 @@ Examples:
 from __future__ import annotations
 
 import argparse
+import os
+from pathlib import Path
+
+# device memory kept out of the KV pool: the step programs' temporaries
+# (logits, prefill activations, gathered prefixes), the compiled code and
+# the allocator's fragmentation
+POOL_HEADROOM_BYTES = 2 << 30
+# pool budget where the device reports no memory limit (the CPU backend)
+CPU_POOL_TOKENS = 1 << 16
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its path.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it itself and no
+    other directory is set here; otherwise the cache is ``.jax_cache/`` at
+    the checkout root, a fixed path so that later runs hit it."""
+    import jax
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(Path(__file__).resolve().parents[3] / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def kv_pool_tokens(cfg, params, *, n_replicas: int = 1) -> int:
+    """KV pool tokens per replica that fit beside ``params`` on the device.
+
+    The pool gets the device's ``bytes_limit`` less the params' bytes and
+    ``POOL_HEADROOM_BYTES``, split over the replicas that share the device
+    and rounded down to a multiple of 64 tokens (whole blocks of any
+    power-of-two size up to 64); all three numbers are printed. A device
+    that reports no limit (the CPU) gets ``CPU_POOL_TOKENS``."""
+    import jax
+    import jax.numpy as jnp
+    dev = jax.devices()[0]
+    limit = (dev.memory_stats() or {}).get("bytes_limit")
+    if limit is None:
+        tokens = CPU_POOL_TOKENS // n_replicas // 64 * 64
+        print(f"[pool] {dev.platform}: no device memory limit; fixed budget "
+              f"{tokens} tokens per replica")
+        return tokens
+    param_bytes = sum(x.nbytes for x in jax.tree.leaves(params))
+    free = limit - param_bytes - POOL_HEADROOM_BYTES
+    per_token = cfg.kv_bytes_per_token(jnp.dtype(cfg.dtype).itemsize)
+    tokens = free // n_replicas // per_token // 64 * 64
+    print(f"[pool] bytes_limit={limit} params={param_bytes} "
+          f"headroom={POOL_HEADROOM_BYTES} -> {tokens} tokens "
+          f"({tokens * per_token} B) per replica x {n_replicas}")
+    if tokens < 64:
+        raise SystemExit(f"no room for a KV pool on {dev.device_kind}")
+    return tokens
 
 
 def main():
@@ -151,10 +204,10 @@ def main():
     args = ap.parse_args()
 
     import jax
-    from repro.compat import use_mesh
+    from jax import set_mesh
     from repro.configs import get_config, reduced
-    from repro.core import (TPU_V5E, H100_PAPER, BatchingConfigurationAdvisor,
-                            ReplicationPlanner, decode_curves, max_batch_for,
+    from repro.core import (BatchingConfigurationAdvisor, ReplicationPlanner,
+                            decode_curves, local_hardware, max_batch_for,
                             prefill_step_terms, replication_sweep,
                             slo_from_reference)
     from repro.launch.mesh import make_test_mesh
@@ -163,8 +216,9 @@ def main():
                                SamplingParams, ServingAPI, sharegpt_like)
     from repro.sharding import rules_for
 
+    enable_compile_cache()
     full_cfg = get_config(args.arch)
-    hw = H100_PAPER if args.arch.startswith(("opt-", "llama-2")) else TPU_V5E
+    hw = local_hardware()
 
     max_batch = args.max_batch
     prefill_chunk = args.prefill_chunk if args.prefill_chunk > 0 else None
@@ -182,7 +236,7 @@ def main():
             curves, slo_s=slo, eps=args.eps,
             prefill_token_s=pf_tok_s).solve()
         print(f"[BCA] {res.summary()}")
-        max_batch = min(res.b_opt, 64) if args.reduced else res.b_opt
+        max_batch = res.b_opt
         if args.prefill_chunk < 0:
             prefill_chunk = res.chunk_tokens
             print(f"[BCA] prefill chunk budget: {prefill_chunk} tok/step")
@@ -199,7 +253,7 @@ def main():
             print(f"[sim] {r.summary()}")
     else:
         n_rep = int(args.replicas)
-    n_rep = max(1, min(n_rep, 8))       # CPU-container sanity cap
+    n_rep = max(1, n_rep)
 
     # speculative decoding: default is advisor-decided — speculate iff the
     # break-even math says the verify compute rides the memory gap at this
@@ -214,17 +268,16 @@ def main():
         if spec_k <= 0:
             spec_k = sp.k            # 0 = keep the engine default
 
-    # real engine run (reduced config on CPU)
     cfg = reduced(full_cfg) if args.reduced else full_cfg
     mesh = make_test_mesh()
     rules = rules_for(mesh)
     params = init_params(cfg, jax.random.PRNGKey(0))
     model = Model(cfg, rules)
-    with use_mesh(mesh):
-        # a fixed KV budget stands in for HBM: replicas split it evenly
-        budget = 1 << 16
-        ecfg = EngineConfig(max_batch=min(max_batch, 64),
-                            kv_pool_tokens=(budget // n_rep) // 64 * 64,
+    with set_mesh(mesh):
+        # co-located replicas split the device's pool evenly
+        ecfg = EngineConfig(max_batch=max_batch,
+                            kv_pool_tokens=kv_pool_tokens(
+                                cfg, params, n_replicas=n_rep),
                             max_model_len=512, prefill_bucket=64,
                             prefix_cache=args.prefix_cache,
                             overlap=args.overlap,

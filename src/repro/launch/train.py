@@ -27,7 +27,7 @@ def main():
 
     import jax
     import jax.numpy as jnp
-    from repro.compat import use_mesh
+    from jax import set_mesh
     from repro.configs import get_config, reduced
     from repro.launch.mesh import make_test_mesh
     from repro.models.model import init_params
@@ -47,7 +47,7 @@ def main():
     step_fn = jax.jit(make_train_step(cfg, rules, opt))
     data = synthetic_batches(cfg, batch=args.batch, seq=args.seq)
 
-    with use_mesh(mesh):
+    with set_mesh(mesh):
         t0 = time.time()
         for i in range(args.steps):
             batch = {k: jnp.asarray(v) for k, v in next(data).items()}

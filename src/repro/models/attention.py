@@ -332,9 +332,9 @@ def paged_self_attn_decode(p, x, k_pool, v_pool, cfg: ArchConfig,
 
     The zero-copy half of the engine's decode data path: instead of a
     gathered ``[B, S_pad, K, hd]`` cache copy, this takes the pool's
-    physical blocks (``k_pool/v_pool: [NB, BS, K, hd]``, possibly the
-    layer-flattened ``[L*NB, ...]`` form with layer offsets pre-added to
-    ``tables``) plus per-request addressing:
+    physical blocks (``k_pool/v_pool: [NB, BS, K*hd]``, lane-dense token
+    rows, possibly the layer-flattened ``[L*NB, ...]`` form with layer
+    offsets pre-added to ``tables``) plus per-request addressing:
 
       tables    [B, nb] int32  physical block per logical block
       lengths   [B]     int32  valid tokens incl. the one written now
@@ -357,8 +357,10 @@ def paged_self_attn_decode(p, x, k_pool, v_pool, cfg: ArchConfig,
     phys = tables[barange, positions // block_size]
     sib = positions % block_size
     with jax.named_scope("kv_update"):
-        k_pool = k_pool.at[phys, sib].set(k_new[:, 0].astype(k_pool.dtype))
-        v_pool = v_pool.at[phys, sib].set(v_new[:, 0].astype(v_pool.dtype))
+        k_pool = k_pool.at[phys, sib].set(
+            k_new[:, 0].reshape(B, -1).astype(k_pool.dtype))
+        v_pool = v_pool.at[phys, sib].set(
+            v_new[:, 0].reshape(B, -1).astype(v_pool.dtype))
     with jax.named_scope("attn_core"):
         # pools are consumed at their storage dtype — the block-table
         # kernels upcast per tile, so no whole-pool astype copy here

@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.configs.base import ArchConfig
 from repro.models.params import pspec
 from repro.models.layers import mlp_abstract, mlp_apply
@@ -178,8 +177,8 @@ def moe_ffn(p, x: jax.Array, cfg: ArchConfig, rules: ShardingRules,
                 P(w_e, None, w_f) if cfg.act == "swiglu" else P())
     out_specs = (P(batch_spec, None), P())
     w3 = p.get("w3", jnp.zeros((), cfg.activation_dtype))
-    fn = compat.shard_map(local_moe, mesh=rules.mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=False)
+    fn = jax.shard_map(local_moe, mesh=rules.mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     out, aux = fn(xs, p["router"], p["w1"], p["w2"], w3)
     out = out.reshape(B, S, d)
     out = constrain(out, rules, (BATCH, SEQ, D_MODEL))

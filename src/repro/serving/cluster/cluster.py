@@ -86,7 +86,7 @@ import time
 from collections import deque
 from typing import Callable, List, Optional, Sequence, Union
 
-from repro.compat import use_mesh
+from jax import set_mesh
 from repro.serving.cluster.metrics import (ClusterMetrics, ReplicaStats,
                                            aggregate)
 from repro.serving.cluster.router import Router, RouterPolicy
@@ -134,7 +134,7 @@ class Replica:
         return self.engine.pool.manager.used_fraction
 
     def mesh_ctx(self):
-        return use_mesh(self.mesh) if self.mesh is not None \
+        return set_mesh(self.mesh) if self.mesh is not None \
             else contextlib.nullcontext()
 
 

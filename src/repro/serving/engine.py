@@ -93,10 +93,11 @@ from typing import Callable, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.configs.base import ArchConfig
-from repro.kvcache.paged import (PagedKVCache, cache_layout,
-                                 gather_prefix_jit, scatter_chunk_jit)
+from repro.kvcache.paged import (PagedKVCache, gather_prefix_jit,
+                                 scatter_rows_jit)
 from repro.kvcache.prefix import PrefixIndex, PrefixStats, \
     prefix_cache_supported
 from repro.kvcache.view import PagedCacheView
@@ -299,12 +300,9 @@ class StepFunctions:
     @classmethod
     def build(cls, model: Model, block_size: int) -> "StepFunctions":
         # zero-copy steps: the pool pytree (arg 1) is donated so the K/V
-        # row scatters alias the input buffers. Donation works on CPU
-        # since jaxlib 0.4.x (the repo's pinned floor) — in-place pool
-        # updates there too, instead of a full pool copy per step (a
-        # ~10x step-time cliff at large pools)
+        # row scatters alias the input buffers, on the TPU and the CPU
+        # alike. A pool sized to fill HBM has no room for a second copy.
         donate = (1,)
-        layout = cache_layout(model.cfg, block_size)
         return cls(
             model=model, block_size=block_size,
             prefill=jax.jit(partial(_prefill_fn, model),
@@ -315,7 +313,7 @@ class StepFunctions:
             prefix_prefill=jax.jit(partial(_prefix_prefill_fn, model),
                                    static_argnames=("cache_len",)),
             chunk_prefill=jax.jit(
-                partial(_chunk_prefill_fn, model, block_size, layout),
+                partial(_chunk_prefill_fn, model, block_size),
                 static_argnames=("cache_len", "nb_prefix"),
                 donate_argnums=donate),
             sample=jax.jit(sample_tokens),
@@ -325,6 +323,14 @@ class StepFunctions:
 
 def _bucket(n: int, b: int) -> int:
     return max(b, ((n + b - 1) // b) * b)
+
+
+def _placement(params) -> jax.sharding.Sharding:
+    """The params' devices, replicated: where the pool and uploads go."""
+    s = jax.tree.leaves(params)[0].sharding
+    if isinstance(s, NamedSharding):
+        return NamedSharding(s.mesh, PartitionSpec())
+    return s
 
 
 def _pow2_bucket(n: int, lo: int = 1) -> int:
@@ -342,9 +348,12 @@ class ContinuousBatchingEngine:
         self.params = params
         self.ecfg = ecfg
         nb = ecfg.kv_pool_tokens // ecfg.block_size
+        # the pool and every per-step upload live where the params do (a
+        # sliced replica's params sit on its own device)
         self.pool = PagedKVCache(self.cfg, num_blocks=nb,
                                  block_size=ecfg.block_size,
-                                 max_batch=ecfg.max_batch)
+                                 max_batch=ecfg.max_batch,
+                                 sharding=_placement(params))
         # ring caches (sliding window) aren't paged — fall back to gather
         self.decode_mode = ("gather" if self.cfg.sliding_window
                             else ecfg.decode_mode)
@@ -795,13 +804,13 @@ class ContinuousBatchingEngine:
             S = _bucket(sfx_len, self.ecfg.prefill_bucket)
             toks = np.zeros((1, S), np.int32)
             toks[0, :sfx_len] = req.prompt[n_cached:]
-            batch = {"tokens": jnp.asarray(toks),
-                     "lengths": jnp.asarray([sfx_len], jnp.int32)}
+            batch = self.pool.put({"tokens": toks, "lengths": np.asarray(
+                [sfx_len], np.int32)})
             nb_cached = n_cached // self.ecfg.block_size
             nb_pad = _pow2_bucket(nb_cached, lo=1)
             prefix_kv = self.pool.gather_prefix(
                 self.pool.manager.tables[rid][:nb_cached], nb_pad)
-            args = (self.params, batch, prefix_kv, jnp.int32(n_cached))
+            args = (self.params, batch, prefix_kv, np.int32(n_cached))
             kw = {"cache_len": S}
             if self.obs is not None:
                 logits, cache, _ = self._observed_call(
@@ -814,12 +823,12 @@ class ContinuousBatchingEngine:
             S = _bucket(req.prompt_len, self.ecfg.prefill_bucket)
             toks = np.zeros((1, S), np.int32)
             toks[0, :req.prompt_len] = req.prompt
-            batch = {"tokens": jnp.asarray(toks),
-                     "lengths": jnp.asarray([req.prompt_len], jnp.int32)}
+            batch = self.pool.put({"tokens": toks, "lengths": np.asarray(
+                [req.prompt_len], np.int32)})
             if self.cfg.arch_type == "vlm":
                 batch["img_embeds"] = jnp.zeros(
                     (1, self.cfg.n_img_tokens, self.cfg.d_model),
-                    self.cfg.activation_dtype)
+                    self.cfg.activation_dtype, device=self.pool.sharding)
             args = (self.params, batch)
             kw = {"cache_len": S}
             if self.obs is not None:
@@ -852,8 +861,8 @@ class ContinuousBatchingEngine:
         S = _bucket(chunk, self.ecfg.prefill_bucket)
         toks = np.zeros((1, S), np.int32)
         toks[0, :chunk] = req.prompt[done:done + chunk]
-        batch = {"tokens": jnp.asarray(toks),
-                 "lengths": jnp.asarray([chunk], jnp.int32)}
+        batch = self.pool.put({"tokens": toks,
+                               "lengths": np.asarray([chunk], np.int32)})
         if done == 0:
             # first chunk of an uncached prompt: plain prefill (identical
             # compute to the serial path when the chunk covers the whole
@@ -873,8 +882,8 @@ class ContinuousBatchingEngine:
             table = np.full((nb_pad,), self.pool.trash_block, np.int32)
             table[:len(blocks)] = blocks
             nb_prefix = _pow2_bucket(-(-done // self.ecfg.block_size), lo=1)
-            args = (self.params, self.pool.pool, jnp.asarray(table), batch,
-                    jnp.int32(done), jnp.int32(chunk))
+            args = (self.params, self.pool.pool, self.pool.put(table), batch,
+                    np.int32(done), np.int32(chunk))
             kw = {"cache_len": S, "nb_prefix": min(nb_prefix, nb_pad)}
             if self.obs is not None:
                 logits, new_pool = self._observed_call(
@@ -1001,12 +1010,10 @@ class ContinuousBatchingEngine:
         view = self.pool.view(rids, positions, nb_pad, batch_pad)
         tokens = np.zeros((batch_pad,), np.int32)
         tokens[:B] = [self._tokens[rid] for rid in rids]
-        temp, top_k, top_p, seed = stack_sampling(
-            [r.sampling for r in reqs], pad_to=batch_pad)
+        uploads = self.pool.put((tokens, *stack_sampling(
+            [r.sampling for r in reqs], pad_to=batch_pad)))
         args = (self.params, view.pool, view.tables, view.lengths,
-                view.positions, view.slots, jnp.asarray(tokens),
-                jnp.asarray(temp), jnp.asarray(top_k), jnp.asarray(top_p),
-                jnp.asarray(seed))
+                view.positions, view.slots, *uploads)
         obs = self.obs
         if obs is not None:
             # census BEFORE the call — the pool arg is donated, so the
@@ -1036,8 +1043,9 @@ class ContinuousBatchingEngine:
         pad_blocks = self.pool.manager.blocks_needed(
             _bucket(max_pos + 1, self.ecfg.block_size * 4))
         view = self.pool.gather(rids, pad_blocks)
-        tokens = jnp.asarray([self._tokens[rid] for rid in rids], jnp.int32)
-        pos = jnp.asarray([self._pos[rid] for rid in rids], jnp.int32)
+        tokens, pos = self.pool.put((
+            np.asarray([self._tokens[rid] for rid in rids], np.int32),
+            np.asarray([self._pos[rid] for rid in rids], np.int32)))
         args = (self.params, view, tokens, pos)
         obs = self.obs
         if obs is not None:
@@ -1104,10 +1112,10 @@ class ContinuousBatchingEngine:
         if samp is None:
             if len(self._spec_samp_cache) > 64:
                 self._spec_samp_cache.clear()
-            samp = tuple(jax.device_put(stack_sampling(
+            samp = tuple(self.pool.put(stack_sampling(
                 [r.sampling for r in reqs], pad_to=batch_pad)))
             self._spec_samp_cache[samp_key] = samp
-        tokens_d, draft_mat_d, draft_len_d = jax.device_put(
+        tokens_d, draft_mat_d, draft_len_d = self.pool.put(
             (tokens, draft_mat, draft_len))
         args = (self.params, view.pool, view.tables, view.lengths,
                 view.positions, view.slots, tokens_d, draft_mat_d,
@@ -1239,7 +1247,7 @@ def _decode_fn(model: Model, params, view, tokens, pos):
     return model.decode_step(params, view, tokens, pos, lengths=pos + 1)
 
 
-def _chunk_prefill_fn(model: Model, block_size: int, layout, params, pool,
+def _chunk_prefill_fn(model: Model, block_size: int, params, pool,
                       tables, batch, prefix_len, n_valid, cache_len: int,
                       nb_prefix: int):
     """One fused chunked-prefill step (jitted; ``pool`` donated).
@@ -1254,14 +1262,13 @@ def _chunk_prefill_fn(model: Model, block_size: int, layout, params, pool,
     never recompiles; ``nb_prefix`` (static) trims the gather to the
     power-of-two block count actually covering the prefix.
     """
-    is_kv, bdim = layout
-    prefix_kv = gather_prefix_jit(pool, is_kv, bdim, tables[:nb_prefix],
+    prefix_kv = gather_prefix_jit(pool, model.cfg, tables[:nb_prefix],
                                   block_size)
     logits, cache, _ = model.prefill(params, batch, cache_len=cache_len,
                                      prefix=prefix_kv,
                                      prefix_len=prefix_len)
-    new_pool = scatter_chunk_jit(pool, cache, is_kv, bdim, tables,
-                                 prefix_len, n_valid, block_size)
+    new_pool = scatter_rows_jit(pool, cache, model.cfg, tables, prefix_len,
+                                n_valid, block_size)
     return logits, new_pool
 
 

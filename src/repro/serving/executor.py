@@ -263,10 +263,10 @@ class Executor:
                 prev_rows[i] = ch[1]
             else:
                 host[i] = eng._tokens[rid]
+        put = self.eng.pool.put
         if prev is None:
-            return jnp.asarray(host)
-        return _chain_tokens(prev.tokens, jnp.asarray(host),
-                             jnp.asarray(prev_rows), jnp.asarray(use_prev))
+            return put(host)
+        return _chain_tokens(prev.tokens, *put((host, prev_rows, use_prev)))
 
     def _dispatch(self, plan: StepPlan):
         eng = self.eng
@@ -307,10 +307,8 @@ class Executor:
         if self._samp_cache[0] != skey:
             temp, top_k, top_p, seed = stack_sampling(
                 [r.sampling for r in plan.reqs], pad_to=batch_pad)
-            self._samp_cache = (skey, (jnp.asarray(temp),
-                                       jnp.asarray(top_k),
-                                       jnp.asarray(top_p),
-                                       jnp.asarray(seed)))
+            self._samp_cache = (skey, eng.pool.put((temp, top_k, top_p,
+                                                    seed)))
         args = (eng.params, view.pool, view.tables, view.lengths,
                 view.positions, view.slots, tokens,
                 *self._samp_cache[1])
@@ -355,13 +353,11 @@ class Executor:
         if self._samp_cache[0] != skey:
             temp, top_k, top_p, seed = stack_sampling(
                 [r.sampling for r in plan.reqs], pad_to=batch_pad)
-            self._samp_cache = (skey, (jnp.asarray(temp),
-                                       jnp.asarray(top_k),
-                                       jnp.asarray(top_p),
-                                       jnp.asarray(seed)))
+            self._samp_cache = (skey, eng.pool.put((temp, top_k, top_p,
+                                                    seed)))
         args = (eng.params, view.pool, view.tables, view.lengths,
                 view.positions, view.slots, tokens,
-                jnp.asarray(draft_mat), jnp.asarray(draft_len),
+                *eng.pool.put((draft_mat, draft_len)),
                 *self._samp_cache[1])
         obs = eng.obs
         sc = None
@@ -394,7 +390,7 @@ class Executor:
             _bucket(max_pos + 1, eng.ecfg.block_size * 4))
         view = eng.pool.gather(rids, pad_blocks)
         tokens = self._input_tokens(rids, len(rids))
-        pos = jnp.asarray(positions, jnp.int32)
+        pos = eng.pool.put(np.asarray(positions, np.int32))
         args = (eng.params, view, tokens, pos)
         obs = eng.obs
         sc = None

@@ -36,7 +36,7 @@ import dataclasses
 import time
 from typing import Dict, List, Optional, Sequence, Union
 
-from repro.core.hardware import TPU_V5E, Hardware
+from repro.core.hardware import Hardware, local_hardware
 from repro.serving.obs.auditor import MemoryGapAuditor
 from repro.serving.obs.roofline import (LiveRoofline, StepCensus,
                                         StepCensusCache)
@@ -409,7 +409,7 @@ class Observability:
 
     ::
 
-        obs = Observability(hw=H100_PAPER)
+        obs = Observability()           # peaks of the local device
         obs.attach(engine)              # or obs.attach_cluster(cluster)
         engine.run(reqs)
         obs.export_chrome_trace("trace.json")
@@ -422,7 +422,7 @@ class Observability:
                  audit_memory: bool = False,
                  windows: Union[bool, WindowAggregator, None] = None,
                  slos: Optional[Sequence[SLO]] = None):
-        self.hw = hw or TPU_V5E
+        self.hw = hw or local_hardware()
         self.trace = Tracer(max_events=max_events)
         self.census = StepCensusCache()
         self.series_maxlen = series_maxlen

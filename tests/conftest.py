@@ -1,7 +1,7 @@
-import jax
 import pytest
+from jax import set_mesh
 
-from repro.compat import make_mesh, use_mesh
+from repro.launch.mesh import make_mesh
 
 
 @pytest.fixture(scope="session")
@@ -19,5 +19,5 @@ def rules(mesh):
 
 @pytest.fixture(autouse=True)
 def _use_mesh(mesh):
-    with use_mesh(mesh):
+    with set_mesh(mesh):
         yield

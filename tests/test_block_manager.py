@@ -146,3 +146,35 @@ def test_cow_pool_copy_preserves_contents(rules):
     for leaf in jax.tree.leaves(view):
         if leaf.ndim == 5:                   # [L, B, S, K, hd] kv leaf
             assert np.allclose(np.asarray(leaf)[:, 0, :16], 5.0)
+
+
+def test_pool_writes_donate_the_pool(rules):
+    """Every pool write runs in a program that donates the pool: the old
+    leaves are deleted, so no write ever holds two copies of the pool."""
+    import jax
+    import jax.numpy as jnp
+    from repro.configs import get_config, reduced
+    from repro.kvcache.paged import PagedKVCache
+    from repro.models import model as M
+
+    cfg = reduced(get_config("opt-1.3b"))
+    pool = PagedKVCache(cfg, num_blocks=8, block_size=8, max_batch=2)
+    pool.manager.allocate(0, 16)
+    cache = jax.tree.map(lambda x: jnp.full_like(x, 5.0),
+                         M.init_cache(cfg, 1, 16))
+
+    def donated(write):
+        old = jax.tree.leaves(pool.pool)
+        write()
+        return all(leaf.is_deleted() for leaf in old)
+
+    assert donated(lambda: pool.write_prefill(0, cache))
+    assert donated(lambda: pool.write_prefill(0, cache, start_pos=3,
+                                              n_tokens=5))
+    assert donated(lambda: pool.scatter_new_token(
+        [0], [10], pool.gather([0], pad_blocks=2)))
+    pool.manager.share(1, pool.manager.tables[0])
+    assert donated(lambda: pool.ensure_writable(1, 12))
+    view = pool.gather([1], pad_blocks=2)
+    for leaf in jax.tree.leaves(view):
+        assert np.allclose(np.asarray(leaf)[:, 0, :16], 5.0)

@@ -4,7 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import make_mesh
+from repro.launch.mesh import make_mesh
 from repro.configs import get_config
 from repro.core import (H100_PAPER, TPU_V5E, BatchingConfigurationAdvisor,
                         HloCensus, ReplicationPlanner, decode_curves,

@@ -10,6 +10,12 @@ from repro.kernels import ref
 from repro.kernels.paged_decode_attention import (
     paged_gqa_decode_attention, paged_gqa_decode_attention_jax)
 
+
+def _flat(pool):
+    """[NB, BS, K, hd] -> the kernels' lane-dense [NB, BS, K*hd] rows."""
+    return pool.reshape(*pool.shape[:2], -1)
+
+
 CASES = [
     # B, K, G, hd, BS, nb, NB, dtype
     (3, 2, 4, 64, 16, 5, 32, jnp.float32),
@@ -29,8 +35,8 @@ def test_paged_decode_vs_gathered_oracle(B, K, G, hd, BS, nb, NB, dtype):
     table = jnp.asarray(perm, jnp.int32)
     lengths = jnp.asarray(
         np.random.default_rng(2).integers(1, BS * nb + 1, B), jnp.int32)
-    out = paged_gqa_decode_attention(q, k_pool, v_pool, table, lengths,
-                                     interpret=True)
+    out = paged_gqa_decode_attention(q, _flat(k_pool), _flat(v_pool),
+                                     table, lengths, interpret=True)
     kc = k_pool[table].reshape(B, nb * BS, K, hd)
     vc = v_pool[table].reshape(B, nb * BS, K, hd)
     exp = ref.gqa_decode_attention_ref(q, kc, vc, lengths)
@@ -52,7 +58,8 @@ def test_paged_jax_path_vs_gathered_oracle(B, K, G, hd, BS, nb, NB, dtype):
     table = jnp.asarray(perm, jnp.int32)
     lengths = jnp.asarray(
         np.random.default_rng(5).integers(1, BS * nb + 1, B), jnp.int32)
-    out = paged_gqa_decode_attention_jax(q, k_pool, v_pool, table, lengths)
+    out = paged_gqa_decode_attention_jax(q, _flat(k_pool), _flat(v_pool),
+                                         table, lengths)
     kc = k_pool[table].reshape(B, nb * BS, K, hd)
     vc = v_pool[table].reshape(B, nb * BS, K, hd)
     exp = ref.gqa_decode_attention_ref(q, kc, vc, lengths)
@@ -72,9 +79,10 @@ def test_paged_jax_path_matches_pallas_interpret():
     perm = np.random.default_rng(7).permutation(NB)[:B * nb].reshape(B, nb)
     table = jnp.asarray(perm, jnp.int32)
     lengths = jnp.asarray([BS * nb, 17], jnp.int32)
-    a = paged_gqa_decode_attention(q, k_pool, v_pool, table, lengths,
-                                   interpret=True)
-    b = paged_gqa_decode_attention_jax(q, k_pool, v_pool, table, lengths)
+    a = paged_gqa_decode_attention(q, _flat(k_pool), _flat(v_pool),
+                                   table, lengths, interpret=True)
+    b = paged_gqa_decode_attention_jax(q, _flat(k_pool), _flat(v_pool),
+                                       table, lengths)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5,
                                rtol=1e-5)
 
@@ -90,7 +98,7 @@ def test_paged_jax_path_zero_length_padding_rows():
     table = jnp.asarray([[0, 1], [2, 3], [7, 7]], jnp.int32)
     lengths = jnp.asarray([10, 4, 0], jnp.int32)
     out = np.asarray(paged_gqa_decode_attention_jax(
-        q, k_pool, v_pool, table, lengths))
+        q, _flat(k_pool), _flat(v_pool), table, lengths))
     assert np.all(out[2] == 0.0)
     assert np.all(np.isfinite(out))
 
@@ -116,5 +124,6 @@ def test_paged_result_independent_of_block_placement():
         v_pool = v_pool.at[table.reshape(-1)].set(
             vc.reshape(B * nb, BS, K, hd))
         outs.append(np.asarray(paged_gqa_decode_attention(
-            q, k_pool, v_pool, table, lengths, interpret=True)))
+            q, _flat(k_pool), _flat(v_pool), table, lengths,
+            interpret=True)))
     np.testing.assert_array_equal(outs[0], outs[1])

@@ -72,7 +72,7 @@ def test_slice_mesh_identity_and_divisibility(mesh):
 
 _SLICE_SCRIPT = """
 import numpy as np
-from repro.compat import make_mesh
+from repro.launch.mesh import make_mesh
 from repro.core.replication import slice_mesh
 
 mesh = make_mesh((4, 1), ("data", "model"))

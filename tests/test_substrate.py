@@ -7,7 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.compat import make_mesh
+from repro.launch.mesh import make_mesh
 from repro.configs import get_config, reduced
 from repro.models import model as M
 from repro.models import ssm as ssm_mod
