@@ -17,10 +17,9 @@ quantitative promises, checked on the real engine.
   ending. Driven on a deterministic synthetic clock so the latency
   bound is exact, not scheduler-noise-limited.
 * **<= 5% decode-step overhead.** Auditing + windowed aggregation ride
-  the same hooks the observability PR bounded; the bound must hold with
-  ``audit_memory=True`` and windows enabled. Same methodology as
-  ``benchmarks/observability.py`` (alternating repeats, best-of medians,
-  bounded escalation).
+  the observer's step hook; the bound must hold with
+  ``audit_memory=True`` and windows enabled (alternating repeats,
+  best-of medians, bounded escalation).
 
 Output follows benchmarks/run.py conventions: ``name,us_per_call,derived``
 CSV on stdout plus machine-readable ``experiments/paper/BENCH_memgap.json``.
@@ -36,7 +35,7 @@ import statistics
 import time
 from typing import Dict, List
 
-OVERHEAD_TARGET = 0.05       # same bar as benchmarks/observability.py
+OVERHEAD_TARGET = 0.05       # decode-step latency, observer on vs off
 ESCALATE_REPEATS = 6
 
 
@@ -199,9 +198,8 @@ def _run_once(model, params, steps, cfg, mesh, n, out, obs=None) -> float:
 def overhead(model, params, steps, cfg, mesh, *, n: int, out: int,
              repeats: int) -> Dict:
     """Decode-step latency with the full auditor + windows stack on vs
-    everything off. Same alternating best-of-medians methodology (and
-    bounded escalation for borderline runs) as the observability
-    benchmark this extends."""
+    everything off: alternating runs, best of medians, bounded
+    escalation for borderline runs."""
     from repro.serving import SLO, Observability
     from repro.serving.obs.windows import STREAM_ITL
     obs = Observability(audit_memory=True, windows=True,

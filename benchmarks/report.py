@@ -24,9 +24,8 @@ from benchmarks.roofline_table import load_records
 
 # every BENCH_*.json the benchmark suite is expected to have written;
 # grows with each PR that adds a benchmarks/<name>.py artifact
-REQUIRED_BENCHES = ("BENCH_faults.json", "BENCH_obs.json",
-                    "BENCH_memgap.json", "BENCH_overlap.json",
-                    "BENCH_speculative.json")
+REQUIRED_BENCHES = ("BENCH_faults.json", "BENCH_memgap.json",
+                    "BENCH_overlap.json", "BENCH_speculative.json")
 
 HISTORY_NAME = "BENCH_history.jsonl"
 

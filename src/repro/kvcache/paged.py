@@ -46,6 +46,11 @@ from repro.models import model as model_lib
 from repro.models.params import ParamSpec
 from repro.sharding import KV_SEQ
 
+# the pool layer's spans (``pool.*``): repro.serving.obs.trace.span, which
+# lists the names, taken from JAX directly since the serving package
+# imports this module
+span = jax.profiler.TraceAnnotation
+
 
 @jax.jit
 def _advance_poslen(pos, lens):
@@ -591,34 +596,36 @@ class PagedKVCache:
         table (a chunk must extend the table first, through the
         watermark-checked :meth:`BlockManager.extend`).
         """
-        table = self.manager.tables.get(req_id, [])
-        cap = len(table) * self.block_size
-        slot = None             # dense state: whole-prompt writes only
-        if n_tokens is None:
-            if start_pos % self.block_size:
+        with span("pool.write"):
+            table = self.manager.tables.get(req_id, [])
+            cap = len(table) * self.block_size
+            slot = None             # dense state: whole-prompt writes only
+            if n_tokens is None:
+                if start_pos % self.block_size:
+                    raise ValueError(
+                        f"start_pos ({start_pos}) must be block-aligned "
+                        f"(block_size={self.block_size}); pass n_tokens "
+                        f"for the token-granular chunk path")
+                # the view's rows up to the table's end
+                S = next((v.shape[-3] for v, kv in zip(
+                    jax.tree.leaves(cache_one), jax.tree.leaves(self._is_kv))
+                    if kv), 0)
+                n_tokens = min(S, cap - start_pos)
+                slot = np.int32(self._slot(req_id))
+            elif n_tokens < 1:
+                raise ValueError(f"n_tokens must be >= 1, got {n_tokens}")
+            elif start_pos + n_tokens > cap:
                 raise ValueError(
-                    f"start_pos ({start_pos}) must be block-aligned "
-                    f"(block_size={self.block_size}); pass n_tokens for the "
-                    f"token-granular chunk path")
-            # the view's rows up to the table's end
-            S = next((v.shape[-3] for v, kv in zip(
-                jax.tree.leaves(cache_one), jax.tree.leaves(self._is_kv))
-                if kv), 0)
-            n_tokens = min(S, cap - start_pos)
-            slot = np.int32(self._slot(req_id))
-        elif n_tokens < 1:
-            raise ValueError(f"n_tokens must be >= 1, got {n_tokens}")
-        elif start_pos + n_tokens > cap:
-            raise ValueError(
-                f"chunk write [{start_pos}, {start_pos + n_tokens}) "
-                f"over-allocates past req {req_id}'s table ({len(table)} "
-                f"blocks x {self.block_size}); extend() the table first")
-        # pow2-padded table: one compiled write per (view width, pad)
-        tbl = np.full((_pow2(len(table)),), self.trash_block, np.int32)
-        tbl[:len(table)] = table
-        self.pool = _write_rows(
-            self.pool, cache_one, tbl, np.int32(start_pos),
-            np.int32(n_tokens), slot, cfg=self.cfg, block_size=self.block_size)
+                    f"chunk write [{start_pos}, {start_pos + n_tokens}) "
+                    f"over-allocates past req {req_id}'s table ({len(table)} "
+                    f"blocks x {self.block_size}); extend() the table first")
+            # pow2-padded table: one compiled write per (view width, pad)
+            tbl = np.full((_pow2(len(table)),), self.trash_block, np.int32)
+            tbl[:len(table)] = table
+            self.pool = _write_rows(
+                self.pool, cache_one, tbl, np.int32(start_pos),
+                np.int32(n_tokens), slot, cfg=self.cfg,
+                block_size=self.block_size)
 
     # ------------------------------------------------------- zero-copy --
     def view(self, req_ids: Sequence[int], positions: Sequence[int],
@@ -650,64 +657,70 @@ class PagedKVCache:
           independent through the model, and nothing ever reads pad
           outputs or the trash block.
         """
-        B = len(req_ids)
-        assert B <= batch_pad
-        ckey = (tuple(req_ids), nb_pad, batch_pad)
-        key = ckey + (self.manager.version,)
-        if self._dev_tables_key != key:
-            if (self._tables_np is not None
-                    and self._dev_tables_key is not None
-                    and self._dev_tables_key[:3] == ckey):
-                # same rows, allocator moved: patch changed rows only
-                table = self._tables_np
-                snap = self._tables_snap
-                changed = False
-                for i, rid in enumerate(req_ids):
-                    blocks = tuple(self.manager.tables.get(rid, [])[:nb_pad])
-                    if snap[i] != blocks:
-                        table[i, :] = self.trash_block
+        with span("pool.view"):
+            B = len(req_ids)
+            assert B <= batch_pad
+            ckey = (tuple(req_ids), nb_pad, batch_pad)
+            key = ckey + (self.manager.version,)
+            if self._dev_tables_key != key:
+                if (self._tables_np is not None
+                        and self._dev_tables_key is not None
+                        and self._dev_tables_key[:3] == ckey):
+                    # same rows, allocator moved: patch changed rows only
+                    table = self._tables_np
+                    snap = self._tables_snap
+                    changed = False
+                    for i, rid in enumerate(req_ids):
+                        blocks = tuple(
+                            self.manager.tables.get(rid, [])[:nb_pad])
+                        if snap[i] != blocks:
+                            table[i, :] = self.trash_block
+                            table[i, :len(blocks)] = blocks
+                            snap[i] = blocks
+                            changed = True
+                    if changed:
+                        self._dev_tables = self.put(table)
+                else:
+                    table = np.full((batch_pad, nb_pad), self.trash_block,
+                                    np.int32)
+                    slots = np.full((batch_pad,), self.trash_slot, np.int32)
+                    snap = [()] * batch_pad
+                    for i, rid in enumerate(req_ids):
+                        blocks = tuple(
+                            self.manager.tables.get(rid, [])[:nb_pad])
                         table[i, :len(blocks)] = blocks
                         snap[i] = blocks
-                        changed = True
-                if changed:
-                    self._dev_tables = self.put(table)
+                        slots[i] = self._slot(rid)
+                    self._tables_np = table
+                    self._tables_snap = snap
+                    self._dev_tables, self._dev_slots = self.put(
+                        (table, slots))
+                self._dev_tables_key = key
+            pt = tuple(positions)
+            cached = self._poslen
+            if (cached is not None and cached[0] == ckey
+                    and all(p == q + 1 for p, q in zip(pt, cached[1]))):
+                dev_pos, dev_lens = _advance_poslen(cached[2], cached[3])
             else:
-                table = np.full((batch_pad, nb_pad), self.trash_block,
-                                np.int32)
-                slots = np.full((batch_pad,), self.trash_slot, np.int32)
-                snap = [()] * batch_pad
-                for i, rid in enumerate(req_ids):
-                    blocks = tuple(self.manager.tables.get(rid, [])[:nb_pad])
-                    table[i, :len(blocks)] = blocks
-                    snap[i] = blocks
-                    slots[i] = self._slot(rid)
-                self._tables_np = table
-                self._tables_snap = snap
-                self._dev_tables, self._dev_slots = self.put((table, slots))
-            self._dev_tables_key = key
-        pt = tuple(positions)
-        cached = self._poslen
-        if (cached is not None and cached[0] == ckey
-                and all(p == q + 1 for p, q in zip(pt, cached[1]))):
-            dev_pos, dev_lens = _advance_poslen(cached[2], cached[3])
-        else:
-            pos = np.zeros((batch_pad,), np.int32)
-            pos[:B] = np.asarray(positions, np.int32)
-            lens = np.zeros((batch_pad,), np.int32)
-            lens[:B] = pos[:B] + 1
-            dev_pos, dev_lens = self.put((pos, lens))
-        self._poslen = (ckey, pt, dev_pos, dev_lens)
-        return PagedCacheView(self.pool, self._dev_tables,
-                              dev_lens, dev_pos,
-                              self._dev_slots, self.block_size)
+                pos = np.zeros((batch_pad,), np.int32)
+                pos[:B] = np.asarray(positions, np.int32)
+                lens = np.zeros((batch_pad,), np.int32)
+                lens[:B] = pos[:B] + 1
+                dev_pos, dev_lens = self.put((pos, lens))
+            self._poslen = (ckey, pt, dev_pos, dev_lens)
+            return PagedCacheView(self.pool, self._dev_tables,
+                                  dev_lens, dev_pos,
+                                  self._dev_slots, self.block_size)
 
     def put(self, tree):
         """Upload host arrays to the pool's device."""
-        return jax.device_put(tree, self.sharding)
+        with span("pool.put"):
+            return jax.device_put(tree, self.sharding)
 
     def commit(self, new_pool):
         """Adopt the pool pytree returned by a zero-copy decode step."""
-        self.pool = new_pool
+        with span("pool.commit"):
+            self.pool = new_pool
 
     # slot assignment for dense (non-paged) state leaves
     def _slot(self, rid: int) -> int:

@@ -24,6 +24,17 @@ POOL_HEADROOM_BYTES = 2 << 30
 CPU_POOL_TOKENS = 1 << 16
 
 
+def start_profile(path: str):
+    """Start the JAX profiler writing under ``path``, with its Python
+    function tracer off (it would slow the host it measures). The
+    program's spans and the device land in one trace on one clock."""
+    import jax
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(path, create_perfetto_trace=True,
+                             profiler_options=opts)
+
+
 def enable_compile_cache() -> str:
     """Turn on JAX's persistent compilation cache and return its path.
 
@@ -169,9 +180,17 @@ def main():
                          "when a step exceeds S seconds (0 = disabled; "
                          "cluster mode only)")
     ap.add_argument("--trace", default=None, metavar="PATH",
-                    help="record request-lifecycle + step-phase spans and "
-                         "write Chrome-trace/Perfetto JSON to PATH (open "
-                         "in ui.perfetto.dev or chrome://tracing)")
+                    help="record request-lifecycle spans and KV/batch "
+                         "counters and write Chrome-trace/Perfetto JSON "
+                         "to PATH (open in ui.perfetto.dev or "
+                         "chrome://tracing)")
+    ap.add_argument("--profile", default=None, metavar="DIR",
+                    help="record a JAX profiler trace of the serving run "
+                         "under DIR: the program's spans (api.*, "
+                         "engine.*, sched.*, pool.*) and the device's "
+                         "programs and ops on one clock (the .xplane.pb "
+                         "for TensorBoard/XProf, perfetto_trace.json.gz "
+                         "for ui.perfetto.dev)")
     ap.add_argument("--metrics-out", default=None, metavar="PATH",
                     help="write the final metrics snapshot to PATH — "
                          "Prometheus text exposition if PATH ends in "
@@ -329,9 +348,9 @@ def main():
                 # single engine = replica 0; kills surface as
                 # InjectedFault (no peer to redrive onto)
                 backend.faults = faults
-        # runtime observability: roofline attribution + lifecycle tracing
-        # attach to the backend; metrics snapshots go through the emitter;
-        # SLOs + the dashboard ride the windows layer
+        # runtime observability: lifecycle tracing attaches to the
+        # backend; metrics snapshots go through the emitter; SLOs + the
+        # dashboard ride the windows layer
         obs = emitter = dash = None
         slos = []
         if args.slo_ttft or args.slo_itl:
@@ -342,7 +361,7 @@ def main():
         if args.trace or args.metrics_out or args.audit_memory \
                 or want_dash or slos:
             from repro.serving import MetricsEmitter, Observability
-            obs = Observability(hw=hw, audit_memory=args.audit_memory,
+            obs = Observability(audit_memory=args.audit_memory,
                                 windows=bool(want_dash or slos
                                              or args.audit_memory),
                                 slos=slos or None)
@@ -361,6 +380,8 @@ def main():
                 import io
                 out = None if args.dashboard else io.StringIO()
                 dash = Dashboard(obs, out=out)
+        if args.profile:
+            start_profile(args.profile)
         try:
             if args.stream:
                 # online path: submit everything through the facade,
@@ -400,6 +421,10 @@ def main():
                 except Exception:
                     pass
             raise
+        finally:
+            if args.profile:
+                jax.profiler.stop_trace()
+                print(f"[profile] trace -> {args.profile}")
         if dash is not None:
             dash.close()
         if emitter is not None:
@@ -413,16 +438,6 @@ def main():
                 print(f"[obs] trace -> {args.trace} "
                       f"({obs.trace.n_events} events; open in "
                       f"ui.perfetto.dev)")
-            for row in obs.roofline_rows():
-                print(f"[obs] {row}")
-            ob0 = obs.observer(0)
-            if ob0 is not None:
-                p = ob0.phase_summary()
-                print(f"[obs] step phases: sched={p['schedule_s']*1e3:.2f}ms "
-                      f"dispatch={p['dispatch_s']*1e3:.2f}ms "
-                      f"device={p['device_s']*1e3:.2f}ms "
-                      f"host={p['host_s']*1e3:.2f}ms "
-                      f"host_gap={p['host_gap_fraction']*100:.0f}%")
             if args.dashboard_html:
                 from repro.serving.obs.dashboard import write_html_report
                 write_html_report(obs, obs.trace.now(), args.dashboard_html,

@@ -20,9 +20,9 @@ from repro.serving.api import (AsyncRequestHandle, AsyncServingAPI,  # noqa
                                GenerationOutput, RequestHandle,
                                ServingAPI)
 from repro.serving.obs import (BoundedSeries, Dashboard,  # noqa
-                               LiveRoofline, MemoryGapAuditor,
+                               MemoryGapAuditor,
                                MetricsEmitter, Observability, SLO,
-                               SLOMonitor, StepPhases, Tracer,
+                               SLOMonitor, Tracer,
                                WindowAggregator, default_slos,
                                lint_prometheus, metrics_from_json,
                                metrics_to_json, prometheus_text,

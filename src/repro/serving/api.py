@@ -29,9 +29,11 @@ Verbs:
 * :meth:`ServingAPI.drain` — run everything in flight to completion and
   return the final outputs; :meth:`ServingAPI.metrics` summarizes the
   session.
+* :meth:`ServingAPI.pump` — run one scheduling round (the ``api.round``
+  span of a profile).
 
-Stepping is cooperative: ``stream()``/``drain()`` drive the backend's
-scheduling loop from the calling thread (one mixed
+Stepping is cooperative: ``pump()``/``stream()``/``drain()`` drive the
+backend's scheduling loop from the calling thread (one mixed
 admission+prefill+decode round per pump), so streaming adds no thread
 machinery and stays deterministic — the property every bit-identity test
 in this repo leans on. ``engine.run()`` and ``cluster.run()`` are thin
@@ -55,6 +57,7 @@ from repro.serving.cluster.cluster import ReplicatedCluster
 from repro.serving.cluster.metrics import ClusterMetrics
 from repro.serving.engine import ContinuousBatchingEngine
 from repro.serving.metrics import ServingMetrics, collect_from_engine
+from repro.serving.obs.trace import span
 from repro.serving.workload import FINISH_ABORT, Request, SamplingParams
 
 
@@ -349,9 +352,8 @@ class ServingAPI:
                                       ReplicatedCluster], *,
                  obs=None, emitter=None, dashboard=None):
         """``obs`` (a :class:`~repro.serving.obs.Observability`) attaches
-        runtime observability to the wrapped backend — roofline
-        attribution, lifecycle tracing, memory-gap auditing — for this
-        session; ``emitter`` (a
+        runtime observability to the wrapped backend — lifecycle
+        tracing, memory-gap auditing — for this session; ``emitter`` (a
         :class:`~repro.serving.obs.MetricsEmitter`) is ticked once per
         scheduling round on the serving timeline, so a streamed session
         emits periodic metrics snapshots without its own timer thread;
@@ -395,19 +397,27 @@ class ServingAPI:
         self._now_floor = max(self._now_floor, self._clock())
         return self._now_floor
 
+    def pump(self) -> bool:
+        """Run one scheduling round (admission, prefill work and one
+        decode step of every running request) from the calling thread;
+        returns whether work remains. ``stream()`` and ``drain()`` are
+        loops over it."""
+        return self._pump_once()
+
     def _pump_once(self) -> bool:
-        ff = self._backend.next_arrival_if_idle()
-        if ff is not None:
-            self._now_floor = max(self._now_floor, ff)
-        busy = self._backend.pump(self._now(), self._clock)
-        if self.emitter is not None:
-            self.emitter.tick(self._now(), self.metrics)
-        if self.obs is not None and self.obs.slo is not None:
-            # tracer timeline: the observers' window pushes use it
-            self.obs.slo.evaluate(self.obs.trace.now())
-        if self.dashboard is not None:
-            self.dashboard.tick(self._now())
-        return busy
+        with span("api.round"):
+            ff = self._backend.next_arrival_if_idle()
+            if ff is not None:
+                self._now_floor = max(self._now_floor, ff)
+            busy = self._backend.pump(self._now(), self._clock)
+            if self.emitter is not None:
+                self.emitter.tick(self._now(), self.metrics)
+            if self.obs is not None and self.obs.slo is not None:
+                # tracer timeline: the observers' window pushes use it
+                self.obs.slo.evaluate(self.obs.trace.now())
+            if self.dashboard is not None:
+                self.dashboard.tick(self._now())
+            return busy
 
     # ---------------------------------------------------------- submit --
     def submit(self, prompt, sampling: Optional[SamplingParams] = None, *,

@@ -113,6 +113,7 @@ from repro.serving.spec import (PromptLookupDrafter, spec_verify_fn,
 from repro.serving.spec.drafter import Drafter
 from repro.serving.spec.verify import accepted_prefix
 from repro.serving.obs.series import DEFAULT_SERIES_MAXLEN, BoundedSeries
+from repro.serving.obs.trace import span
 from repro.serving.workload import (FINISH_ABORT, FINISH_DEADLINE,
                                     FINISH_FAILED, FINISH_LENGTH,
                                     FINISH_SHED, FINISH_STOP, Request)
@@ -197,7 +198,7 @@ class EngineConfig:
     # keep rows unchained and the probes stop costing anything.
     spec_probe_every: int = 8
     # bound on every per-step telemetry series (ITL, KV occupancy, stall,
-    # token splits, preemptions, observability phase/roofline samples):
+    # token splits, preemptions, the observer's audits):
     # a series reaching this length decimates itself (uniform 1-in-N
     # downsampling over the whole run) instead of growing — soak runs
     # keep O(1) host memory per series. See serving.obs.series.
@@ -305,20 +306,33 @@ class StepFunctions:
         donate = (1,)
         return cls(
             model=model, block_size=block_size,
-            prefill=jax.jit(partial(_prefill_fn, model),
+            prefill=jax.jit(_named("prefill", _prefill_fn, model),
                             static_argnames=("cache_len",)),
-            decode=jax.jit(partial(_decode_fn, model)),
-            paged=jax.jit(partial(_paged_decode_fn, model, block_size),
+            decode=jax.jit(_named("decode_gather", _decode_fn, model)),
+            paged=jax.jit(_named("paged_decode_step", _paged_decode_fn,
+                                 model, block_size),
                           donate_argnums=donate),
-            prefix_prefill=jax.jit(partial(_prefix_prefill_fn, model),
-                                   static_argnames=("cache_len",)),
+            prefix_prefill=jax.jit(
+                _named("prefix_prefill", _prefix_prefill_fn, model),
+                static_argnames=("cache_len",)),
             chunk_prefill=jax.jit(
-                partial(_chunk_prefill_fn, model, block_size),
+                _named("chunk_prefill", _chunk_prefill_fn, model,
+                       block_size),
                 static_argnames=("cache_len", "nb_prefix"),
                 donate_argnums=donate),
-            sample=jax.jit(sample_tokens),
-            spec_verify=jax.jit(partial(spec_verify_fn, model, block_size),
+            sample=jax.jit(_named("sample", sample_tokens)),
+            spec_verify=jax.jit(_named("spec_verify", spec_verify_fn,
+                                       model, block_size),
                                 donate_argnums=donate))
+
+
+def _named(name: str, fn: Callable, *bound) -> Callable:
+    """``partial(fn, *bound)`` named ``name``: JAX names the program
+    ``jit_<name>`` (a bare partial is ``jit__unknown``), which is how a
+    profile tells the step programs apart."""
+    f = partial(fn, *bound)
+    f.__name__ = f.__qualname__ = name
+    return f
 
 
 def _bucket(n: int, b: int) -> int:
@@ -444,8 +458,7 @@ class ContinuousBatchingEngine:
         # an EngineObserver here
         self.obs = None
         # last decode step's jit-bucketing facts, stashed only when an
-        # observer is attached: (batch_pad, nb_pad, live_table_entries) —
-        # the memory-gap auditor's bucket-pad overlay input
+        # observer is attached (see _stash_buckets)
         self._last_buckets = None
         # telemetry — every per-step series is bounded (decimating, see
         # serving.obs.series) so soak runs cannot grow host memory
@@ -559,8 +572,6 @@ class ContinuousBatchingEngine:
         if req.sampling.has_deadline:
             self._has_deadlines = True
         self.waiting.append(req)
-        if self.obs is not None:
-            self.obs.on_submit(req)
 
     # ----------------------------------------------- admission control --
     # (logic lives on the Scheduler since the scheduler/executor split;
@@ -754,9 +765,10 @@ class ContinuousBatchingEngine:
         the same first token for the same seed), decode bookkeeping,
         prefix-index registration, TTFT stamp, finish-or-run."""
         rid = req.req_id
-        tok = int(self._steps.sample(
-            logits, *stack_sampling([req.sampling]),
-            positions_array([req.prompt_len]))[0])
+        with span("engine.fetch"):
+            tok = int(self._steps.sample(
+                logits, *stack_sampling([req.sampling]),
+                positions_array([req.prompt_len]))[0])
         self._tokens[rid] = tok
         self._pos[rid] = req.prompt_len
         # prefill's token counts as dispatched AND committed (the int()
@@ -769,28 +781,6 @@ class ContinuousBatchingEngine:
             # register the prompt's full blocks (prefix + own) for reuse
             self.prefix.insert(req.prompt, self.pool.manager.tables[rid])
         self._post_prefill(req, now)
-
-    def _observed_call(self, req: Request, variant: str, fn, args: tuple,
-                       kw: dict, tokens: int, bucket: tuple):
-        """Run one jitted prefill-family call under the observer: census
-        its shape bucket (AOT-compiled once, cached — see
-        ``serving.obs.roofline``), time dispatch vs device completion,
-        and emit the compute span + roofline sample. Only reached when
-        ``self.obs`` is attached; obs-off paths call the jit directly.
-
-        ``bucket`` must carry every integer the call's traced shapes
-        derive from (the cheap cache key — see ``StepCensusCache.get``).
-        The census is taken *before* executing (``fn.lower`` must see the
-        donated pool buffer still alive on the chunked path)."""
-        obs = self.obs
-        sc = obs.census.get(variant, fn, args, kw, bucket=bucket)
-        t0 = time.perf_counter()
-        out = fn(*args, **kw)
-        t1 = time.perf_counter()
-        jax.block_until_ready(out)
-        t2 = time.perf_counter()
-        obs.on_prefill(req, variant, sc, t0, t1, t2, tokens)
-        return out
 
     def _prefill(self, req: Request, n_cached: int = 0):
         """Serial whole-prompt prefill: compute + write the KV; returns
@@ -810,14 +800,10 @@ class ContinuousBatchingEngine:
             nb_pad = _pow2_bucket(nb_cached, lo=1)
             prefix_kv = self.pool.gather_prefix(
                 self.pool.manager.tables[rid][:nb_cached], nb_pad)
-            args = (self.params, batch, prefix_kv, np.int32(n_cached))
-            kw = {"cache_len": S}
-            if self.obs is not None:
-                logits, cache, _ = self._observed_call(
-                    req, "prefix_prefill", self._prefix_prefill_jit,
-                    args, kw, tokens=sfx_len, bucket=(S, nb_pad))
-            else:
-                logits, cache, _ = self._prefix_prefill_jit(*args, **kw)
+            with span("engine.prefill"):
+                logits, cache, _ = self._prefix_prefill_jit(
+                    self.params, batch, prefix_kv, np.int32(n_cached),
+                    cache_len=S)
             self.pool.write_prefill(rid, cache, start_pos=n_cached)
         else:
             S = _bucket(req.prompt_len, self.ecfg.prefill_bucket)
@@ -829,14 +815,9 @@ class ContinuousBatchingEngine:
                 batch["img_embeds"] = jnp.zeros(
                     (1, self.cfg.n_img_tokens, self.cfg.d_model),
                     self.cfg.activation_dtype, device=self.pool.sharding)
-            args = (self.params, batch)
-            kw = {"cache_len": S}
-            if self.obs is not None:
-                logits, cache, _ = self._observed_call(
-                    req, "prefill", self._prefill_jit, args, kw,
-                    tokens=req.prompt_len, bucket=(S,))
-            else:
-                logits, cache, _ = self._prefill_jit(*args, **kw)
+            with span("engine.prefill"):
+                logits, cache, _ = self._prefill_jit(self.params, batch,
+                                                     cache_len=S)
             self.pool.write_prefill(rid, cache)
         self.prefill_tokens_computed += req.prompt_len - n_cached
         return logits
@@ -867,14 +848,9 @@ class ContinuousBatchingEngine:
             # first chunk of an uncached prompt: plain prefill (identical
             # compute to the serial path when the chunk covers the whole
             # prompt — the bit-identity anchor) + token-granular write
-            args = (self.params, batch)
-            kw = {"cache_len": S}
-            if self.obs is not None:
-                logits, cache, _ = self._observed_call(
-                    req, "prefill", self._prefill_jit, args, kw,
-                    tokens=chunk, bucket=(S,))
-            else:
-                logits, cache, _ = self._prefill_jit(*args, **kw)
+            with span("engine.prefill"):
+                logits, cache, _ = self._prefill_jit(self.params, batch,
+                                                     cache_len=S)
             self.pool.write_prefill(rid, cache, start_pos=0, n_tokens=chunk)
         else:
             blocks = self.pool.manager.tables[rid]
@@ -882,15 +858,12 @@ class ContinuousBatchingEngine:
             table = np.full((nb_pad,), self.pool.trash_block, np.int32)
             table[:len(blocks)] = blocks
             nb_prefix = _pow2_bucket(-(-done // self.ecfg.block_size), lo=1)
-            args = (self.params, self.pool.pool, self.pool.put(table), batch,
-                    np.int32(done), np.int32(chunk))
-            kw = {"cache_len": S, "nb_prefix": min(nb_prefix, nb_pad)}
-            if self.obs is not None:
-                logits, new_pool = self._observed_call(
-                    req, "chunk_prefill", self._chunk_prefill_jit, args,
-                    kw, tokens=chunk, bucket=(S, nb_pad, kw["nb_prefix"]))
-            else:
-                logits, new_pool = self._chunk_prefill_jit(*args, **kw)
+            tables = self.pool.put(table)
+            with span("engine.prefill"):
+                logits, new_pool = self._chunk_prefill_jit(
+                    self.params, self.pool.pool, tables, batch,
+                    np.int32(done), np.int32(chunk), cache_len=S,
+                    nb_prefix=min(nb_prefix, nb_pad))
             self.pool.commit(new_pool)
         self.prefill_tokens_computed += chunk
         return logits
@@ -928,16 +901,22 @@ class ContinuousBatchingEngine:
         visible in ITL; the prefill share of each step is recorded
         separately in ``stall_samples``.
         """
-        self.step_count += 1
-        if self.faults is not None:
-            # may sleep (delay — the watchdog's trigger) or raise
-            # InjectedFault (kill — the cluster's quarantine trigger);
-            # raised on the host before any mutation *and before any
-            # dispatch*, so injected faults stay ordered even in overlap
-            # mode (only genuine device errors defer — see executor)
-            self.faults.on_step(self.replica_id, self.step_count)
-        if self.ecfg.overlap:
-            return self._executor.step(now)
+        with span("engine.step"):
+            self.step_count += 1
+            if self.faults is not None:
+                # may sleep (delay — the watchdog's trigger) or raise
+                # InjectedFault (kill — the cluster's quarantine trigger);
+                # raised on the host before any mutation *and before any
+                # dispatch*, so injected faults stay ordered even in
+                # overlap mode (only genuine device errors defer — see
+                # executor)
+                self.faults.on_step(self.replica_id, self.step_count)
+            if self.ecfg.overlap:
+                return self._executor.step(now)
+            return self._step_sync(now)
+
+    def _step_sync(self, now: float) -> bool:
+        """One synchronous iteration (see :meth:`step`)."""
         plan = self.sched.plan(now)
         t0, t_sched, n_prefill = plan.t0, plan.t_sched, plan.n_prefill
         if not plan.has_decode:
@@ -952,8 +931,7 @@ class ContinuousBatchingEngine:
                 self.max_kv_fraction = max(self.max_kv_fraction,
                                            self.pool.manager.used_fraction)
                 if self.obs is not None:
-                    self.obs.end_step(self, t0=t0, t_sched_s=t_sched,
-                                      n_prefill=n_prefill, n_decode=0)
+                    self.obs.end_step(self, n_prefill=n_prefill, n_decode=0)
             return self.busy
         reqs = plan.reqs
         if plan.drafts is not None:
@@ -965,35 +943,36 @@ class ContinuousBatchingEngine:
             next_tokens = self._decode_paged(reqs)
         else:
             next_tokens = self._decode_gather(reqs)
-        dt = time.perf_counter() - t0
-        self.itl_samples.append(dt)
-        self.stall_samples.append(t_sched)
-        self.prefill_token_samples.append(n_prefill)
-        self.decode_token_samples.append(len(reqs))
-        self.preemption_samples.append(self.preemptions - plan.p0)
-        self.batch_samples.append(len(reqs))
-        self.kv_fraction_samples.append(self.pool.manager.used_fraction)
-        self.max_kv_fraction = max(self.max_kv_fraction,
-                                   self.pool.manager.used_fraction)
-        # bookkeeping (no TTFT re-stamp here: _post_prefill always stamps
-        # t_first_token when prefill emits the first token, and preempted
-        # requests get re-stamped on re-admission — a re-stamp on decode
-        # could only mis-stamp). Sync mode advances ``_pos`` here, at
-        # commit (overlap advances it at plan time — see Scheduler.plan).
-        still = []
-        for i, r in enumerate(reqs):
-            self._pos[r.req_id] += 1
-            tok = int(next_tokens[i])
-            self._tokens[r.req_id] = tok
-            r.state.generated += 1
-            r.state.output_tokens.append(tok)
-            if not self._finish_or_run(r, now + dt):
-                still.append(r)
-        self.running = still
-        if self.obs is not None:
-            # last statement of the step: the host phase runs to here
-            self.obs.end_step(self, t0=t0, t_sched_s=t_sched,
-                              n_prefill=n_prefill, n_decode=len(reqs))
+        with span("engine.commit"):
+            dt = time.perf_counter() - t0
+            self.itl_samples.append(dt)
+            self.stall_samples.append(t_sched)
+            self.prefill_token_samples.append(n_prefill)
+            self.decode_token_samples.append(len(reqs))
+            self.preemption_samples.append(self.preemptions - plan.p0)
+            self.batch_samples.append(len(reqs))
+            self.kv_fraction_samples.append(self.pool.manager.used_fraction)
+            self.max_kv_fraction = max(self.max_kv_fraction,
+                                       self.pool.manager.used_fraction)
+            # bookkeeping (no TTFT re-stamp here: _post_prefill always
+            # stamps t_first_token when prefill emits the first token,
+            # and preempted requests get re-stamped on re-admission — a
+            # re-stamp on decode could only mis-stamp). Sync mode
+            # advances ``_pos`` here, at commit (overlap advances it at
+            # plan time — see Scheduler.plan).
+            still = []
+            for i, r in enumerate(reqs):
+                self._pos[r.req_id] += 1
+                tok = int(next_tokens[i])
+                self._tokens[r.req_id] = tok
+                r.state.generated += 1
+                r.state.output_tokens.append(tok)
+                if not self._finish_or_run(r, now + dt):
+                    still.append(r)
+            self.running = still
+            if self.obs is not None:
+                self.obs.end_step(self, n_prefill=n_prefill,
+                                  n_decode=len(reqs), itl_s=dt)
         return True
 
     # ------------------------------------------------------ decode paths --
@@ -1001,74 +980,59 @@ class ContinuousBatchingEngine:
         """Zero-copy step: block-table attention directly on the pool,
         next token sampled inside the same jit (per-request params ride
         as traced [B] vectors; padding rows are greedy and discarded)."""
-        B = len(reqs)
-        rids = [r.req_id for r in reqs]
-        positions = [self._pos[rid] for rid in rids]
-        max_blocks = max(len(self.pool.manager.tables[rid]) for rid in rids)
-        nb_pad = _pow2_bucket(max_blocks, lo=4)
-        batch_pad = _pow2_bucket(B)
-        view = self.pool.view(rids, positions, nb_pad, batch_pad)
-        tokens = np.zeros((batch_pad,), np.int32)
-        tokens[:B] = [self._tokens[rid] for rid in rids]
-        uploads = self.pool.put((tokens, *stack_sampling(
-            [r.sampling for r in reqs], pad_to=batch_pad)))
-        args = (self.params, view.pool, view.tables, view.lengths,
+        with span("engine.decode"):
+            B = len(reqs)
+            rids = [r.req_id for r in reqs]
+            positions = [self._pos[rid] for rid in rids]
+            max_blocks = max(len(self.pool.manager.tables[rid])
+                             for rid in rids)
+            nb_pad = _pow2_bucket(max_blocks, lo=4)
+            batch_pad = _pow2_bucket(B)
+            view = self.pool.view(rids, positions, nb_pad, batch_pad)
+            tokens = np.zeros((batch_pad,), np.int32)
+            tokens[:B] = [self._tokens[rid] for rid in rids]
+            uploads = self.pool.put((tokens, *stack_sampling(
+                [r.sampling for r in reqs], pad_to=batch_pad)))
+            next_tokens, new_pool = self._paged_jit(
+                self.params, view.pool, view.tables, view.lengths,
                 view.positions, view.slots, *uploads)
-        obs = self.obs
-        if obs is not None:
-            # census BEFORE the call — the pool arg is donated, so the
-            # AOT lowering must see the buffer while it is still alive
-            sc = obs.census.get("decode", self._paged_jit, args,
-                                bucket=(batch_pad, nb_pad))
-            t0 = time.perf_counter()
-            next_tokens, new_pool = self._paged_jit(*args)
-            t1 = time.perf_counter()
-            jax.block_until_ready((next_tokens, new_pool))
-            t2 = time.perf_counter()
-            obs.on_decode(sc, t0, t1, t2, batch=B)
-            tables = self.pool.manager.tables
-            self._last_buckets = (
-                batch_pad, nb_pad,
-                sum(min(len(tables[rid]), nb_pad) for rid in rids))
-        else:
-            next_tokens, new_pool = self._paged_jit(*args)
-        self.pool.commit(new_pool)
-        return np.asarray(next_tokens)[:B]
+            self.pool.commit(new_pool)
+            if self.obs is not None:
+                self._stash_buckets(rids, batch_pad, nb_pad)
+        with span("engine.fetch"):
+            return np.asarray(next_tokens)[:B]
+
+    def _stash_buckets(self, rids: List[int], batch_pad: int, nb_pad: int):
+        """Keep the decode step's jit buckets for the memory-gap
+        auditor's bucket-pad overlay: (batch_pad, nb_pad, live table
+        entries). Only called while an observer is attached."""
+        tables = self.pool.manager.tables
+        self._last_buckets = (batch_pad, nb_pad, sum(
+            min(len(tables[rid]), nb_pad) for rid in rids))
 
     def _decode_gather(self, reqs: List[Request]) -> np.ndarray:
         """Legacy dense-copy step (documented fallback); sampling runs as
         a separate jitted call on the returned logits."""
-        rids = [r.req_id for r in reqs]
-        max_pos = max(self._pos[rid] for rid in rids)
-        pad_blocks = self.pool.manager.blocks_needed(
-            _bucket(max_pos + 1, self.ecfg.block_size * 4))
-        view = self.pool.gather(rids, pad_blocks)
-        tokens, pos = self.pool.put((
-            np.asarray([self._tokens[rid] for rid in rids], np.int32),
-            np.asarray([self._pos[rid] for rid in rids], np.int32)))
-        args = (self.params, view, tokens, pos)
-        obs = self.obs
-        if obs is not None:
-            sc = obs.census.get("decode_gather", self._decode_jit, args,
-                                bucket=(len(rids), pad_blocks))
-            t0 = time.perf_counter()
-            logits, new_cache = self._decode_jit(*args)
-            t1 = time.perf_counter()
-            jax.block_until_ready((logits, new_cache))
-            t2 = time.perf_counter()
-            obs.on_decode(sc, t0, t1, t2, batch=len(reqs))
-            tables = self.pool.manager.tables
-            self._last_buckets = (
-                len(rids), pad_blocks,
-                sum(min(len(tables[rid]), pad_blocks) for rid in rids))
-        else:
-            logits, new_cache = self._decode_jit(*args)
-        self.pool.scatter_new_token(rids, [self._pos[r] for r in rids],
-                                    new_cache)
-        next_tokens = self._steps.sample(
-            logits, *stack_sampling([r.sampling for r in reqs]),
-            positions_array([self._pos[rid] + 1 for rid in rids]))
-        return np.asarray(next_tokens)
+        with span("engine.decode"):
+            rids = [r.req_id for r in reqs]
+            max_pos = max(self._pos[rid] for rid in rids)
+            pad_blocks = self.pool.manager.blocks_needed(
+                _bucket(max_pos + 1, self.ecfg.block_size * 4))
+            view = self.pool.gather(rids, pad_blocks)
+            tokens, pos = self.pool.put((
+                np.asarray([self._tokens[rid] for rid in rids], np.int32),
+                np.asarray([self._pos[rid] for rid in rids], np.int32)))
+            logits, new_cache = self._decode_jit(self.params, view, tokens,
+                                                 pos)
+            if self.obs is not None:
+                self._stash_buckets(rids, len(rids), pad_blocks)
+            self.pool.scatter_new_token(rids, [self._pos[r] for r in rids],
+                                        new_cache)
+            next_tokens = self._steps.sample(
+                logits, *stack_sampling([r.sampling for r in reqs]),
+                positions_array([self._pos[rid] + 1 for rid in rids]))
+        with span("engine.fetch"):
+            return np.asarray(next_tokens)
 
     # ------------------------------------------------ speculative decode --
     def rollback_kv(self, rid: int, n_tokens: int):
@@ -1087,11 +1051,11 @@ class ContinuousBatchingEngine:
         return self.pool.rollback(rid, n_tokens)
 
     def _verify_paged(self, plan: StepPlan):
-        """Dispatch + fetch one multi-token verify step (sync mode).
+        """Dispatch one multi-token verify step (sync mode).
 
         Same bucketing discipline as ``_decode_paged`` plus a pow2 K
         bucket: the jit cache stays O(log batch x log tables x log K).
-        Returns host ``(ys, oks)`` sliced to the live batch.
+        Returns the device ``(ys, oks)`` of the padded batch.
         """
         reqs, rids, positions = plan.reqs, plan.rids, plan.positions
         drafts = plan.drafts
@@ -1117,28 +1081,14 @@ class ContinuousBatchingEngine:
             self._spec_samp_cache[samp_key] = samp
         tokens_d, draft_mat_d, draft_len_d = self.pool.put(
             (tokens, draft_mat, draft_len))
-        args = (self.params, view.pool, view.tables, view.lengths,
-                view.positions, view.slots, tokens_d, draft_mat_d,
-                draft_len_d, *samp)
-        obs = self.obs
-        if obs is not None:
-            sc = obs.census.get("spec_verify", self._spec_verify_jit, args,
-                                bucket=(batch_pad, nb_pad, k_pad))
-            t0 = time.perf_counter()
-            ys, oks, new_pool = self._spec_verify_jit(*args)
-            t1 = time.perf_counter()
-            jax.block_until_ready((ys, oks, new_pool))
-            t2 = time.perf_counter()
-            obs.on_decode(sc, t0, t1, t2, batch=B, variant="spec_verify")
-            tables = self.pool.manager.tables
-            self._last_buckets = (
-                batch_pad, nb_pad,
-                sum(min(len(tables[rid]), nb_pad) for rid in rids))
-        else:
-            ys, oks, new_pool = self._spec_verify_jit(*args)
+        ys, oks, new_pool = self._spec_verify_jit(
+            self.params, view.pool, view.tables, view.lengths,
+            view.positions, view.slots, tokens_d, draft_mat_d, draft_len_d,
+            *samp)
         self.pool.commit(new_pool)
-        ys_np, oks_np = jax.device_get((ys, oks))   # one fetch, one sync
-        return ys_np[:B], oks_np[:B]
+        if self.obs is not None:
+            self._stash_buckets(rids, batch_pad, nb_pad)
+        return ys, oks
 
     def _spec_commit(self, plan: StepPlan, ys: np.ndarray, oks: np.ndarray,
                      t_done: float, valid: Optional[List[bool]] = None
@@ -1202,21 +1152,26 @@ class ContinuousBatchingEngine:
         samples count *committed* tokens — tokens-per-step > batch is
         the speculation win made visible)."""
         reqs = plan.reqs
-        ys, oks = self._verify_paged(plan)
-        dt = time.perf_counter() - plan.t0
-        committed = self._spec_commit(plan, ys, oks, now + dt)
-        self.itl_samples.append(dt)
-        self.stall_samples.append(plan.t_sched)
-        self.prefill_token_samples.append(plan.n_prefill)
-        self.decode_token_samples.append(committed)
-        self.preemption_samples.append(self.preemptions - plan.p0)
-        self.batch_samples.append(len(reqs))
-        self.kv_fraction_samples.append(self.pool.manager.used_fraction)
-        self.max_kv_fraction = max(self.max_kv_fraction,
-                                   self.pool.manager.used_fraction)
-        if self.obs is not None:
-            self.obs.end_step(self, t0=plan.t0, t_sched_s=plan.t_sched,
-                              n_prefill=plan.n_prefill, n_decode=len(reqs))
+        with span("engine.decode"):
+            ys, oks = self._verify_paged(plan)
+        with span("engine.fetch"):
+            ys, oks = jax.device_get((ys, oks))   # one fetch, one sync
+        with span("engine.commit"):
+            dt = time.perf_counter() - plan.t0
+            committed = self._spec_commit(plan, ys, oks, now + dt)
+            self.itl_samples.append(dt)
+            self.stall_samples.append(plan.t_sched)
+            self.prefill_token_samples.append(plan.n_prefill)
+            self.decode_token_samples.append(committed)
+            self.preemption_samples.append(self.preemptions - plan.p0)
+            self.batch_samples.append(len(reqs))
+            self.kv_fraction_samples.append(
+                self.pool.manager.used_fraction)
+            self.max_kv_fraction = max(self.max_kv_fraction,
+                                       self.pool.manager.used_fraction)
+            if self.obs is not None:
+                self.obs.end_step(self, n_prefill=plan.n_prefill,
+                                  n_decode=len(reqs), itl_s=dt)
 
     # --------------------------------------------------------------- run --
     def run(self, requests: List[Request]) -> ServingMetrics:

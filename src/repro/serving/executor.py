@@ -6,7 +6,7 @@ engine threw that away: every decode path called ``np.asarray`` /
 ``jax.block_until_ready`` on its outputs before doing host bookkeeping,
 so scheduling, detokenization fan-out, and metrics all serialized with
 device execution and the device idled for the whole host phase of every
-step (the ``host_gap_fraction`` the observability layer measures).
+step.
 
 This module is the *dispatch* half of the scheduler/executor split. Under
 ``EngineConfig.overlap`` each engine iteration ``i`` runs:
@@ -54,6 +54,11 @@ mode. A genuine device-side error from step N, however, surfaces at the
 deferred fetch during iteration N+1; the executor annotates the exception
 with the originating engine step (``err.engine_step = N`` plus an
 ``add_note`` on Python >= 3.11) so attribution stays unambiguous.
+
+Spans (``repro.serving.obs.trace.span``): ``engine.decode`` around
+each dispatch, ``engine.fetch`` around each deferred token readback and
+``engine.commit`` around each commit's bookkeeping, all inside the
+engine's ``engine.step``.
 """
 from __future__ import annotations
 
@@ -65,6 +70,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models.sampler import positions_array, stack_sampling
+from repro.serving.obs.trace import span
 from repro.serving.scheduler import StepPlan
 
 
@@ -79,21 +85,11 @@ def _chain_tokens_fn(prev, host_tokens, prev_rows, use_prev):
 _chain_tokens = jax.jit(_chain_tokens_fn)
 
 
-def _is_ready(arr) -> bool:
-    """Non-blocking completion probe (jax.Array.is_ready; jax >= 0.4)."""
-    try:
-        return bool(arr.is_ready())
-    except AttributeError:      # pragma: no cover - very old jax
-        return False
-
-
 class InFlightStep:
     """One dispatched-but-uncommitted decode (or verify) step."""
-    __slots__ = ("plan", "tokens", "batch", "valid", "sc",
-                 "t_call", "t_ret", "t_seen_ready", "oks")
+    __slots__ = ("plan", "tokens", "batch", "valid", "oks")
 
-    def __init__(self, plan: StepPlan, tokens, batch: int, sc,
-                 t_call: float, t_ret: float):
+    def __init__(self, plan: StepPlan, tokens, batch: int):
         self.plan = plan
         self.tokens = tokens          # device array; [batch_pad] or [B]
         # verify steps ([batch_pad, K+1] tokens): the acceptance mask
@@ -103,12 +99,6 @@ class InFlightStep:
         # their request finishes / aborts / expires / is preempted while
         # the step is still in flight
         self.valid = [True] * batch
-        self.sc = sc                  # StepCensus (obs attached) or None
-        self.t_call = t_call          # perf_counter at dispatch call
-        self.t_ret = t_ret            # perf_counter at dispatch return
-        # first step start at which a non-blocking probe saw the result
-        # ready (tightens the completion-time estimate — see _commit)
-        self.t_seen_ready: Optional[float] = None
 
 
 class Executor:
@@ -144,10 +134,6 @@ class Executor:
         # planning until the commit pins their post-acceptance length
         self._spec_pending: set = set()
         self._t_last_commit: Optional[float] = None
-        # previous committed step's estimated device-completion time and
-        # dispatch-call time (the overlap attribution anchors)
-        self._prev_ready_est: Optional[float] = None
-        self._prev_t_call: Optional[float] = None
         self._preempt_seen = 0
 
     # ---------------------------------------------------------- control --
@@ -159,15 +145,13 @@ class Executor:
         self._spec_pending.clear()
         self._samp_cache = (None, None)
         self._t_last_commit = None
-        self._prev_ready_est = None
-        self._prev_t_call = None
 
     def invalidate(self, rid: int):
         """A request left the active set (finish / abort / deadline /
         preempt / evict): discard its uncommitted in-flight rows so the
         speculative tokens never reach ``output_tokens``, and drop any
         step whose rows are now all dead (it commits nothing and emits no
-        phase sample)."""
+        telemetry)."""
         self._chain.pop(rid, None)
         self._spec_pending.discard(rid)
         if not self._inflight:
@@ -185,19 +169,10 @@ class Executor:
     def step(self, now: float) -> bool:
         """One overlapped iteration: plan i, dispatch i, commit i-1."""
         eng = self.eng
-        t_start = time.perf_counter()
-        # non-blocking probe: if the in-flight result is already on
-        # device-complete before we even start planning, remember when we
-        # saw it — the commit's completion-time estimate uses it to
-        # expose host-bound gaps that a fetch-after-dispatch loop would
-        # otherwise hide (the fetch then never waits, so fetch timing
-        # alone always reads "device was ready", gap 0)
-        for entry in self._inflight:
-            if entry.t_seen_ready is None and _is_ready(entry.tokens):
-                entry.t_seen_ready = t_start
         plan = eng.sched.plan(now)
         if plan.has_decode:
-            self._dispatch(plan)
+            with span("engine.decode"):
+                self._dispatch(plan)
         # commit everything beyond the retained window; with no new
         # dispatch this drains the pipeline (idle / prefill-only /
         # all-at-budget iterations still retire in-flight work)
@@ -216,8 +191,7 @@ class Executor:
             eng.max_kv_fraction = max(eng.max_kv_fraction,
                                       eng.pool.manager.used_fraction)
             if eng.obs is not None:
-                eng.obs.end_step(eng, t0=plan.t0, t_sched_s=plan.t_sched,
-                                 n_prefill=plan.n_prefill, n_decode=0)
+                eng.obs.end_step(eng, n_prefill=plan.n_prefill, n_decode=0)
         return eng.busy or bool(self._inflight)
 
     # --------------------------------------------------------- dispatch --
@@ -290,8 +264,8 @@ class Executor:
     def _dispatch_paged(self, plan: StepPlan) -> InFlightStep:
         """The zero-copy decode dispatch, fetch deferred: identical args
         to the sync ``_decode_paged`` (same jit, same buckets, same
-        sampling stack), minus the ``block_until_ready`` and the
-        ``np.asarray`` — the result stays a device future."""
+        sampling stack), minus the ``np.asarray`` — the result stays a
+        device future."""
         from repro.serving.engine import _pow2_bucket
         eng = self.eng
         rids, positions = plan.rids, plan.positions
@@ -309,27 +283,13 @@ class Executor:
                 [r.sampling for r in plan.reqs], pad_to=batch_pad)
             self._samp_cache = (skey, eng.pool.put((temp, top_k, top_p,
                                                     seed)))
-        args = (eng.params, view.pool, view.tables, view.lengths,
-                view.positions, view.slots, tokens,
-                *self._samp_cache[1])
-        obs = eng.obs
-        sc = None
-        if obs is not None:
-            # census BEFORE the call — the pool arg is donated, so the
-            # AOT lowering must see the buffer while it is still alive
-            sc = obs.census.get("decode", eng._paged_jit, args,
-                                bucket=(batch_pad, nb_pad))
-        t_call = time.perf_counter()
-        next_tokens, new_pool = eng._paged_jit(*args)
-        t_ret = time.perf_counter()
-        if obs is not None:
-            tables = eng.pool.manager.tables
-            eng._last_buckets = (
-                batch_pad, nb_pad,
-                sum(min(len(tables[rid]), nb_pad) for rid in rids))
+        next_tokens, new_pool = eng._paged_jit(
+            eng.params, view.pool, view.tables, view.lengths,
+            view.positions, view.slots, tokens, *self._samp_cache[1])
+        if eng.obs is not None:
+            eng._stash_buckets(rids, batch_pad, nb_pad)
         eng.pool.commit(new_pool)
-        return InFlightStep(plan, next_tokens, batch=B, sc=sc,
-                            t_call=t_call, t_ret=t_ret)
+        return InFlightStep(plan, next_tokens, batch=B)
 
     def _dispatch_verify(self, plan: StepPlan) -> InFlightStep:
         """Speculative verify dispatch, fetch deferred: same jit and
@@ -355,26 +315,14 @@ class Executor:
                 [r.sampling for r in plan.reqs], pad_to=batch_pad)
             self._samp_cache = (skey, eng.pool.put((temp, top_k, top_p,
                                                     seed)))
-        args = (eng.params, view.pool, view.tables, view.lengths,
-                view.positions, view.slots, tokens,
-                *eng.pool.put((draft_mat, draft_len)),
-                *self._samp_cache[1])
-        obs = eng.obs
-        sc = None
-        if obs is not None:
-            sc = obs.census.get("spec_verify", eng._spec_verify_jit, args,
-                                bucket=(batch_pad, nb_pad, k_pad))
-        t_call = time.perf_counter()
-        ys, oks, new_pool = eng._spec_verify_jit(*args)
-        t_ret = time.perf_counter()
-        if obs is not None:
-            tables = eng.pool.manager.tables
-            eng._last_buckets = (
-                batch_pad, nb_pad,
-                sum(min(len(tables[rid]), nb_pad) for rid in rids))
+        ys, oks, new_pool = eng._spec_verify_jit(
+            eng.params, view.pool, view.tables, view.lengths,
+            view.positions, view.slots, tokens,
+            *eng.pool.put((draft_mat, draft_len)), *self._samp_cache[1])
+        if eng.obs is not None:
+            eng._stash_buckets(rids, batch_pad, nb_pad)
         eng.pool.commit(new_pool)
-        entry = InFlightStep(plan, ys, batch=B, sc=sc,
-                             t_call=t_call, t_ret=t_ret)
+        entry = InFlightStep(plan, ys, batch=B)
         entry.oks = oks
         return entry
 
@@ -391,128 +339,69 @@ class Executor:
         view = eng.pool.gather(rids, pad_blocks)
         tokens = self._input_tokens(rids, len(rids))
         pos = eng.pool.put(np.asarray(positions, np.int32))
-        args = (eng.params, view, tokens, pos)
-        obs = eng.obs
-        sc = None
-        if obs is not None:
-            sc = obs.census.get("decode_gather", eng._decode_jit, args,
-                                bucket=(len(rids), pad_blocks))
-        t_call = time.perf_counter()
-        logits, new_cache = eng._decode_jit(*args)
+        logits, new_cache = eng._decode_jit(eng.params, view, tokens, pos)
         eng.pool.scatter_new_token(rids, positions, new_cache)
         next_tokens = eng._steps.sample(
             logits, *stack_sampling([r.sampling for r in plan.reqs]),
             positions_array([p + 1 for p in positions]))
-        t_ret = time.perf_counter()
-        if obs is not None:
-            tables = eng.pool.manager.tables
-            eng._last_buckets = (
-                len(rids), pad_blocks,
-                sum(min(len(tables[rid]), pad_blocks) for rid in rids))
-        return InFlightStep(plan, next_tokens, batch=len(rids), sc=sc,
-                            t_call=t_call, t_ret=t_ret)
+        if eng.obs is not None:
+            eng._stash_buckets(rids, len(rids), pad_blocks)
+        return InFlightStep(plan, next_tokens, batch=len(rids))
 
     # ----------------------------------------------------------- commit --
+    def _fetch(self, entry: InFlightStep, *arrays):
+        """Host copies of ``arrays`` (already resident in steady state).
+        A deferred device error is attributed to the step that produced
+        it: the fetch is one iteration behind the dispatch."""
+        plan = entry.plan
+        try:
+            with span("engine.fetch"):
+                return [np.asarray(a) for a in arrays]
+        except Exception as err:
+            err.engine_step = plan.step
+            if hasattr(err, "add_note"):
+                kind = ("speculative verify" if plan.drafts is not None
+                        else "step")
+                err.add_note(
+                    f"deferred device error from engine step {plan.step} "
+                    f"({kind} dispatched under overlap; surfaced at the "
+                    f"next iteration's commit)")
+            raise
+
     def _commit(self, entry: InFlightStep):
-        """Retire one in-flight step: fetch its tokens (already resident
-        in steady state), run the legacy bookkeeping + finish protocol
-        for every still-valid row, and stamp telemetry with commit-time
-        semantics."""
+        """Retire one in-flight step: fetch its tokens, run the legacy
+        bookkeeping + finish protocol for every still-valid row, and
+        stamp telemetry with commit-time semantics."""
         eng = self.eng
         plan = entry.plan
         if plan.drafts is not None:
             self._commit_verify(entry)
             return
-        t_fetch_call = time.perf_counter()
-        waited = not _is_ready(entry.tokens)
-        try:
-            host_tokens = np.asarray(entry.tokens)
-        except Exception as err:
-            # deferred device error: the fetch is one iteration behind
-            # the dispatch, so attribute it to the step that produced it
-            err.engine_step = plan.step
-            if hasattr(err, "add_note"):
-                err.add_note(
-                    f"deferred device error from engine step {plan.step} "
-                    f"(dispatched under overlap; surfaced at the next "
-                    f"iteration's commit)")
-            raise
-        t_fetch_ret = time.perf_counter()
-        # best estimate of when the device actually finished this step:
-        # exact when the fetch had to wait; the probe timestamp when a
-        # step-start probe saw it done; else the fetch-call time (a
-        # documented underestimate — it completed some time before we
-        # looked, so gaps read conservatively large, never small)
-        if waited:
-            ready_est = t_fetch_ret
-        elif entry.t_seen_ready is not None:
-            ready_est = entry.t_seen_ready
-        else:
-            ready_est = t_fetch_call
-        # serving-timeline completion stamp, mirroring sync's ``now + dt``
-        t_done = plan.now + (time.perf_counter() - plan.t0)
-        n_valid = 0
-        for i, r in enumerate(plan.reqs):
-            if not entry.valid[i]:
-                continue
-            n_valid += 1
-            rid = r.req_id
-            tok = int(host_tokens[i])
-            eng._tokens[rid] = tok
-            ch = self._chain.get(rid)
-            if ch is not None and ch[0] is entry:
-                del self._chain[rid]
-            r.state.generated += 1
-            r.state.output_tokens.append(tok)
-            # may _finish -> invalidate(rid): the request's speculative
-            # row in the step dispatched moments ago dies here
-            eng._finish_or_run(r, t_done)
-        eng.running = [r for r in eng.running
-                       if r.state.finish_reason is None]
-        t_host_done = time.perf_counter()
-        if n_valid == 0:          # pragma: no cover - dropped eagerly
-            return
-        # telemetry: same series as the sync loop, commit-time semantics
-        # (ITL = inter-commit cadence — what a streaming client observes)
-        dt = (t_host_done - self._t_last_commit
-              if self._t_last_commit is not None
-              else t_host_done - plan.t0)
-        self._t_last_commit = t_host_done
-        eng.itl_samples.append(dt)
-        eng.stall_samples.append(plan.t_sched)
-        eng.prefill_token_samples.append(plan.n_prefill)
-        eng.decode_token_samples.append(n_valid)
-        delta = max(0, eng.preemptions - self._preempt_seen)
-        self._preempt_seen = eng.preemptions
-        eng.preemption_samples.append(delta)
-        eng.batch_samples.append(n_valid)
-        eng.kv_fraction_samples.append(eng.pool.manager.used_fraction)
-        eng.max_kv_fraction = max(eng.max_kv_fraction,
-                                  eng.pool.manager.used_fraction)
-        if eng.obs is not None:
-            prev_ready = self._prev_ready_est
-            # device idle before this step's dispatch (the host gap the
-            # overlap is supposed to close) / how far ahead of the
-            # previous step's completion the dispatch landed (the win)
-            gap_s = (max(0.0, entry.t_call - prev_ready)
-                     if prev_ready is not None else 0.0)
-            ahead_s = (max(0.0, prev_ready - entry.t_ret)
-                       if prev_ready is not None else 0.0)
-            dev0 = (max(entry.t_ret, prev_ready)
-                    if prev_ready is not None else entry.t_ret)
-            device_s = max(ready_est - dev0, 0.0)
-            total_s = (entry.t_call - self._prev_t_call
-                       if self._prev_t_call is not None
-                       else entry.t_call - plan.t0)
-            eng.obs.end_step_overlap(
-                eng, step=plan.step, t0=plan.t0, t_sched_s=plan.t_sched,
-                n_prefill=plan.n_prefill, n_decode=n_valid, sc=entry.sc,
-                batch=entry.batch, t_call=entry.t_call, t_ret=entry.t_ret,
-                dev0=dev0, dev1=max(ready_est, dev0), gap_s=gap_s,
-                dispatch_ahead_s=ahead_s, total_s=max(total_s, 0.0),
-                host_s=t_host_done - t_fetch_ret)
-        self._prev_ready_est = ready_est
-        self._prev_t_call = entry.t_call
+        host_tokens, = self._fetch(entry, entry.tokens)
+        with span("engine.commit"):
+            # serving-timeline completion stamp, mirroring sync's
+            # ``now + dt``
+            t_done = plan.now + (time.perf_counter() - plan.t0)
+            n_valid = 0
+            for i, r in enumerate(plan.reqs):
+                if not entry.valid[i]:
+                    continue
+                n_valid += 1
+                rid = r.req_id
+                tok = int(host_tokens[i])
+                eng._tokens[rid] = tok
+                ch = self._chain.get(rid)
+                if ch is not None and ch[0] is entry:
+                    del self._chain[rid]
+                r.state.generated += 1
+                r.state.output_tokens.append(tok)
+                # may _finish -> invalidate(rid): the request's
+                # speculative row in the step dispatched moments ago dies
+                eng._finish_or_run(r, t_done)
+            eng.running = [r for r in eng.running
+                           if r.state.finish_reason is None]
+            if n_valid:
+                self._telemetry(plan, n_valid, n_valid)
 
     def _commit_verify(self, entry: InFlightStep):
         """Retire one in-flight speculative verify step: fetch tokens +
@@ -522,44 +411,31 @@ class Executor:
         are skipped — their blocks are already released)."""
         eng = self.eng
         plan = entry.plan
-        t_fetch_call = time.perf_counter()
-        waited = not _is_ready(entry.tokens)
-        try:
-            ys = np.asarray(entry.tokens)
-            oks = np.asarray(entry.oks)
-        except Exception as err:
-            err.engine_step = plan.step
-            if hasattr(err, "add_note"):
-                err.add_note(
-                    f"deferred device error from engine step {plan.step} "
-                    f"(speculative verify dispatched under overlap; "
-                    f"surfaced at the next iteration's commit)")
-            raise
-        t_fetch_ret = time.perf_counter()
-        if waited:
-            ready_est = t_fetch_ret
-        elif entry.t_seen_ready is not None:
-            ready_est = entry.t_seen_ready
-        else:
-            ready_est = t_fetch_call
-        for rid in plan.rids:
-            self._spec_pending.discard(rid)
-        t_done = plan.now + (time.perf_counter() - plan.t0)
-        n_valid = sum(entry.valid)
-        committed = eng._spec_commit(plan, ys, oks, t_done,
-                                     valid=entry.valid)
-        t_host_done = time.perf_counter()
-        if n_valid == 0:          # pragma: no cover - dropped eagerly
-            return
-        dt = (t_host_done - self._t_last_commit
-              if self._t_last_commit is not None
-              else t_host_done - plan.t0)
-        self._t_last_commit = t_host_done
+        ys, oks = self._fetch(entry, entry.tokens, entry.oks)
+        with span("engine.commit"):
+            for rid in plan.rids:
+                self._spec_pending.discard(rid)
+            t_done = plan.now + (time.perf_counter() - plan.t0)
+            n_valid = sum(entry.valid)
+            committed = eng._spec_commit(plan, ys, oks, t_done,
+                                         valid=entry.valid)
+            if n_valid:
+                # tokens-per-commit can exceed the batch: the speculation
+                # win
+                self._telemetry(plan, n_valid, committed)
+
+    def _telemetry(self, plan: StepPlan, n_valid: int, n_tokens: int):
+        """The sync loop's per-step series, with commit-time semantics
+        (ITL = inter-commit cadence: what a streaming client observes)."""
+        eng = self.eng
+        t = time.perf_counter()
+        dt = t - (self._t_last_commit if self._t_last_commit is not None
+                  else plan.t0)
+        self._t_last_commit = t
         eng.itl_samples.append(dt)
         eng.stall_samples.append(plan.t_sched)
         eng.prefill_token_samples.append(plan.n_prefill)
-        # tokens-per-commit can exceed the batch — the speculation win
-        eng.decode_token_samples.append(committed)
+        eng.decode_token_samples.append(n_tokens)
         delta = max(0, eng.preemptions - self._preempt_seen)
         self._preempt_seen = eng.preemptions
         eng.preemption_samples.append(delta)
@@ -568,22 +444,5 @@ class Executor:
         eng.max_kv_fraction = max(eng.max_kv_fraction,
                                   eng.pool.manager.used_fraction)
         if eng.obs is not None:
-            prev_ready = self._prev_ready_est
-            gap_s = (max(0.0, entry.t_call - prev_ready)
-                     if prev_ready is not None else 0.0)
-            ahead_s = (max(0.0, prev_ready - entry.t_ret)
-                       if prev_ready is not None else 0.0)
-            dev0 = (max(entry.t_ret, prev_ready)
-                    if prev_ready is not None else entry.t_ret)
-            total_s = (entry.t_call - self._prev_t_call
-                       if self._prev_t_call is not None
-                       else entry.t_call - plan.t0)
-            eng.obs.end_step_overlap(
-                eng, step=plan.step, t0=plan.t0, t_sched_s=plan.t_sched,
-                n_prefill=plan.n_prefill, n_decode=n_valid, sc=entry.sc,
-                batch=entry.batch, t_call=entry.t_call, t_ret=entry.t_ret,
-                dev0=dev0, dev1=max(ready_est, dev0), gap_s=gap_s,
-                dispatch_ahead_s=ahead_s, total_s=max(total_s, 0.0),
-                host_s=t_host_done - t_fetch_ret, variant="spec_verify")
-        self._prev_ready_est = ready_est
-        self._prev_t_call = entry.t_call
+            eng.obs.end_step(eng, n_prefill=plan.n_prefill,
+                             n_decode=n_valid, itl_s=dt)

@@ -1,7 +1,7 @@
-"""Runtime bottleneck observability (the paper's offline Nsight-style
-analysis as in-band serving telemetry): per-step roofline attribution,
-request-lifecycle Chrome/Perfetto tracing, and registry-backed metrics
-export. See :mod:`repro.serving.obs.observer` for the wiring overview.
+"""Runtime observability: request-lifecycle Chrome/Perfetto tracing, the
+program's profiler spans (``trace.span``), the memory-gap auditor,
+windows and SLOs, and registry-backed metrics export. See
+:mod:`repro.serving.obs.observer` for the wiring overview.
 
 Submodule attributes resolve lazily (PEP 562): the engine imports
 ``obs.series`` at module load, and an eager ``obs.export`` import here
@@ -12,11 +12,8 @@ import importlib
 
 _EXPORTS = {
     "BoundedSeries": "series", "DEFAULT_SERIES_MAXLEN": "series",
-    "Tracer": "trace", "validate_chrome_trace": "trace",
-    "LiveRoofline": "roofline", "RooflineSample": "roofline",
-    "StepCensus": "roofline", "StepCensusCache": "roofline",
+    "Tracer": "trace", "span": "trace", "validate_chrome_trace": "trace",
     "EngineObserver": "observer", "Observability": "observer",
-    "StepPhases": "observer",
     "CLUSTER_SPECS": "export", "SERVING_SPECS": "export",
     "MetricSpec": "export", "MetricsEmitter": "export",
     "lint_prometheus": "export", "metrics_from_json": "export",

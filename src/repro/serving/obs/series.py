@@ -1,8 +1,8 @@
 """Bounded per-step telemetry series.
 
 Every per-step series the engine keeps (ITL, KV occupancy, stall,
-prefill/decode token counts, preemptions, and the observability layer's
-phase/roofline samples) grows by one element per engine step. A soak run
+prefill/decode token counts, preemptions, and the memory auditor's
+samples) grows by one element per engine step. A soak run
 at ~1 kHz of steps would grow host memory without limit; the serving
 layer therefore stores them in :class:`BoundedSeries`, a ``list``
 subclass with a hard length bound.

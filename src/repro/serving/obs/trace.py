@@ -1,30 +1,44 @@
-"""Chrome-trace / Perfetto JSON event collector.
+"""The program's spans, and the request-lifecycle Chrome-trace JSON.
 
-The serving layer's request-lifecycle and step-phase spans are recorded
-as `Trace Event Format` objects (the JSON schema both ``chrome://tracing``
-and https://ui.perfetto.dev load directly):
+**Spans** (``span``): every layer boundary of one engine iteration
+opens a ``jax.profiler.TraceAnnotation``. With the profiler running they
+land on its host plane, on the clock its device planes use, so each gap
+in the device's work falls under the layer the host was in; with the
+profiler off a span costs one object creation (about a microsecond).
+The names are fixed, one prefix per layer:
+
+* ``api.round``: one scheduling round of the facade (``ServingAPI``);
+* ``engine.step`` (one engine iteration), ``engine.prefill`` (a
+  prefill-family program call), ``engine.decode`` (the decode
+  dispatch: one per decode step), ``engine.fetch`` (the token
+  readback), ``engine.commit`` (per-request bookkeeping and the finish
+  protocol);
+* ``sched.plan`` (deadlines, admission, prefill work, capacity, batch
+  selection) and ``sched.admit`` inside it;
+* ``pool.view`` (the step's block tables, lengths and positions),
+  ``pool.put`` (every host-to-device upload), ``pool.write`` (a prefill's
+  pool write) and ``pool.commit``.
+
+No span opens once per request inside a per-step loop.
+
+**Chrome trace** (:class:`Tracer`): `Trace Event Format` objects (the
+JSON schema both ``chrome://tracing`` and https://ui.perfetto.dev load
+directly), stamped on a host ``time.perf_counter`` epoch:
 
 * one **process row per replica** (``pid`` = replica index, named
-  ``replica<N>``),
-* ``tid 0`` is the replica's *engine step* track — one ``X`` span per
-  engine step with nested ``schedule`` / ``dispatch`` / ``device`` /
-  ``host`` phase spans,
+  ``replica<N>``); ``tid 0`` carries its KV/batch counter tracks and the
+  cluster's instants;
 * every request gets its own thread row (``tid`` = ``req_id + 1``)
-  carrying its lifecycle: ``queued`` span (submit -> admission),
-  ``prefill`` / ``chunk`` compute spans, a ``first_token`` instant,
-  a ``decode`` span (first token -> finish), and instants for
-  ``preempt`` / ``redrive`` / ``shed`` / ``deadline`` / ``abort``.
+  carrying its lifecycle: ``queued`` span (arrival -> admission), a
+  ``first_token`` instant, a ``decode`` span (first token -> finish), and
+  instants for ``preempt`` / ``redrive`` / ``shed`` / ``deadline`` /
+  ``abort``.
 
-Timestamps are microseconds on one shared ``time.perf_counter`` epoch
-(fixed when the tracer is created), so spans recorded from different
-replica threads land on one coherent timeline. Appends are plain
-``list.append`` of a small dict — safe under the GIL from concurrent
-replica threads and cheap enough to leave enabled.
-
-The event buffer is bounded (``max_events``): once full, new events are
-dropped and counted in ``dropped`` (exported as trace metadata), so a
-soak run cannot grow host memory without limit — same policy as
-:mod:`repro.serving.obs.series`.
+Appends are plain ``list.append`` of a small dict, safe under the GIL
+from concurrent replica threads. The event buffer is bounded
+(``max_events``): once full, new events are dropped and counted in
+``dropped`` (exported as trace metadata), so a soak run cannot grow host
+memory without limit, the same policy as :mod:`repro.serving.obs.series`.
 """
 from __future__ import annotations
 
@@ -33,7 +47,13 @@ import os
 import time
 from typing import Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 DEFAULT_MAX_EVENTS = 1_000_000
+
+# ``with span("engine.decode"): ...``: a span of the program's layers on
+# the profiler's host plane (names in the module docstring)
+span = TraceAnnotation
 
 
 class Tracer:

@@ -55,6 +55,7 @@ from typing import Dict, List, Optional, TYPE_CHECKING
 
 import numpy as np
 
+from repro.serving.obs.trace import span
 from repro.serving.workload import FINISH_DEADLINE, FINISH_SHED, Request
 
 if TYPE_CHECKING:   # pragma: no cover - import cycle guard (typing only)
@@ -70,9 +71,9 @@ class StepPlan:
     are pre-advance (the slot this step's token occupies). A plan with
     no decode rows (``rids == []``) is a prefill-only / idle iteration.
 
-    ``t0``/``t_sched`` carry the step timer anchors so telemetry and the
-    observer attribute the schedule phase to the right wall-clock span
-    even when the plan commits an iteration later (overlap mode).
+    ``t0``/``t_sched`` carry the step timer anchors so telemetry
+    attributes the schedule phase to the right wall-clock span even when
+    the plan commits an iteration later (overlap mode).
     """
     step: int                     # engine step_count that produced it
     now: float                    # serving-timeline stamp of the plan
@@ -280,6 +281,7 @@ class Scheduler:
                     continue                # retry the same head request
                 break
             self.waiting.popleft()
+            req.state.t_admitted = max(now, eng._now(now))
             if eng.obs is not None:
                 eng.obs.on_admit(req)
             if hit:
@@ -474,12 +476,18 @@ class Scheduler:
         raise before calling plan), always *before* any decode dispatch,
         so host bookkeeping stays consistent on the error paths.
         """
+        with span("sched.plan"):
+            return self._plan(now)
+
+    def _plan(self, now: float) -> StepPlan:
+        """The body of :meth:`plan`."""
         eng = self.eng
         t0 = time.perf_counter()
         pf0 = eng.prefill_tokens_computed
         p0 = eng.preemptions
         self.expire_deadlines(now)
-        self.admit(now)
+        with span("sched.admit"):
+            self.admit(now)
         self.prefill_step(now)
         n_prefill = eng.prefill_tokens_computed - pf0
         t_sched = time.perf_counter() - t0

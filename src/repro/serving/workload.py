@@ -157,6 +157,9 @@ class RequestState:
     everything else observes them through the ``Request`` proxies or as
     :class:`~repro.serving.api.GenerationOutput` stream events.
     """
+    # serving-timeline stamp of the (latest) admission out of the arrival
+    # queue; None while queued
+    t_admitted: Optional[float] = None
     t_first_token: Optional[float] = None
     t_done: Optional[float] = None
     generated: int = 0
@@ -168,6 +171,7 @@ class RequestState:
         re-admission regenerates it from scratch. The terminal fields
         (``t_done``/``finish_reason``) are by construction still unset —
         finished requests are never preempted."""
+        self.t_admitted = None
         self.t_first_token = None
         self.generated = 0
         self.output_tokens = []

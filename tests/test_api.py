@@ -417,3 +417,30 @@ def test_metrics_wall_anchored_at_first_submit(setup, rules):
     m = api.metrics()
     assert m.wall_s < 100.0, "pre-submit idle counted into wall_s"
     assert m.output_tokens == 3
+
+
+# ------------------------------------------------------------ one round ---
+def test_pump_runs_one_round(setup, rules):
+    cfg, _ = setup
+    eng = _engine(setup, rules)
+    api = ServingAPI(eng)
+    assert api.pump() is False                 # nothing submitted: idle
+    handles = [api.submit(_prompt(cfg, 12, seed=s),
+                          SamplingParams(max_new_tokens=3))
+               for s in range(2)]
+    rounds, busy = 0, True
+    while busy:
+        busy = api.pump()
+        rounds += 1
+        assert eng.step_count == rounds        # one round, one step
+    # round 1: admission, prefill (token 1) and a decode step (token 2);
+    # round 2: the last token
+    assert rounds == 2
+    assert all(h.done and h.finish_reason == "length" for h in handles)
+    # the same tokens a stream-driven session produces
+    again = ServingAPI(_engine(setup, rules))
+    outs = [again.generate(_prompt(cfg, 12, seed=s),
+                           SamplingParams(max_new_tokens=3))
+            for s in range(2)]
+    assert [list(h.final_output().token_ids) for h in handles] == \
+        [list(o.token_ids) for o in outs]
