@@ -57,8 +57,9 @@ def _advance_poslen(pos, lens):
     """Steady-state decode advances every row's write position and
     length by exactly one: one fused device bump of the cached vectors
     replaces two host rebuilds + uploads per step (see
-    :meth:`PagedKVCache.view`)."""
-    return pos + 1, lens + 1
+    :meth:`PagedKVCache.view`). Padding rows keep length 0, so the paged
+    kernel copies and computes nothing for them."""
+    return pos + 1, lens + (lens > 0)
 
 
 class BlockManager:
@@ -651,8 +652,8 @@ class PagedKVCache:
         * positions/lengths advance by exactly one for every row in an
           unchanged composition, so steady-state steps bump the cached
           device vectors with one tiny fused jit instead of two host
-          rebuilds + uploads. Padding lanes then drift to small nonzero
-          positions/lengths (instead of staying 0), which is
+          rebuilds + uploads. Padding lanes keep length 0 and their
+          positions drift to small nonzero values, which is
           unobservable: pad rows address the trash block/slot, rows are
           independent through the model, and nothing ever reads pad
           outputs or the trash block.

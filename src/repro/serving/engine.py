@@ -491,6 +491,10 @@ class ContinuousBatchingEngine:
         self.spec_accepted = 0       # draft tokens accepted (committed)
         self.spec_rejected = 0       # draft tokens rejected (rolled back)
         self.spec_acceptance_samples: List[float] = BoundedSeries(ml)
+        # paged kernel engagement, per layer, summed over plain paged
+        # decode steps (see _count_kernel_blocks)
+        self.kernel_live_blocks = 0
+        self.kernel_table_blocks = 0
 
     # -------------------------------------------- scheduler state views --
     # The scheduler owns this state since the scheduler/executor split;
@@ -627,6 +631,8 @@ class ContinuousBatchingEngine:
         self.spec_accepted = 0
         self.spec_rejected = 0
         self.spec_acceptance_samples = BoundedSeries(ml)
+        self.kernel_live_blocks = 0
+        self.kernel_table_blocks = 0
         self._last_buckets = None
         self.pool.manager.total_allocations = 0
         self.pool.manager.cow_copies = 0
@@ -997,10 +1003,23 @@ class ContinuousBatchingEngine:
                 self.params, view.pool, view.tables, view.lengths,
                 view.positions, view.slots, *uploads)
             self.pool.commit(new_pool)
+            self._count_kernel_blocks(positions, batch_pad, nb_pad)
             if self.obs is not None:
                 self._stash_buckets(rids, batch_pad, nb_pad)
         with span("engine.fetch"):
             return np.asarray(next_tokens)[:B]
+
+    def _count_kernel_blocks(self, positions: List[int], batch_pad: int,
+                             nb_pad: int):
+        """Add one paged decode step to the kernel's engagement counters:
+        the pool blocks it copies per layer (each row's ``ceil(length /
+        block_size)`` with ``length = position + 1``, at most ``nb_pad``;
+        padding rows have length 0) and the blocks of its padded
+        ``[batch_pad, nb_pad]`` table. Host numbers only: no sync."""
+        bs = self.ecfg.block_size
+        self.kernel_live_blocks += sum(min(p // bs + 1, nb_pad)
+                                       for p in positions)
+        self.kernel_table_blocks += batch_pad * nb_pad
 
     def _stash_buckets(self, rids: List[int], batch_pad: int, nb_pad: int):
         """Keep the decode step's jit buckets for the memory-gap

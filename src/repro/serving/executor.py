@@ -286,6 +286,7 @@ class Executor:
         next_tokens, new_pool = eng._paged_jit(
             eng.params, view.pool, view.tables, view.lengths,
             view.positions, view.slots, tokens, *self._samp_cache[1])
+        eng._count_kernel_blocks(positions, batch_pad, nb_pad)
         if eng.obs is not None:
             eng._stash_buckets(rids, batch_pad, nb_pad)
         eng.pool.commit(new_pool)

@@ -1,9 +1,9 @@
-"""Ahead-of-time compiles for a described TPU v5e chip at opt-1.3b widths.
+"""Ahead-of-time compiles for a described TPU v5e chip at the served widths.
 
 Nothing runs: the TPU compiler, installed with JAX, compiles for a chip
 that is described and not attached, and refuses what the chip would
 refuse (a block shape off Mosaic's tiling, a program that does not fit
-HBM). The paged kernel is compiled on its own at two batch sizes, the
+HBM). The paged kernel is compiled on its own at both served widths, the
 engine's decode step with depth cut to 2 layers (widths unchanged), and
 the pool writes; each must keep the pool in place (aliased, no
 pool-sized temporary).
@@ -77,16 +77,24 @@ def _pool_sds(sharding, cfg, n_blocks, max_batch):
     return jax.tree.map(lambda x: _sds(sharding, x.shape, x.dtype), pool)
 
 
-@pytest.mark.parametrize("batch", [8, 256])
-def test_paged_kernel_compiles_for_v5e(one_chip, on_chip, batch):
-    """opt-1.3b decode attention over a layer-flattened 24 x 2048-block
-    pool: 32 KV heads of 64 lanes, lane-dense [NB, 16, 2048] rows."""
-    cfg = get_config("opt-1.3b")
+@pytest.mark.parametrize("arch,batch,nb", [
+    pytest.param("opt-1.3b", 8, 32, id="8"),
+    pytest.param("opt-1.3b", 256, 32, id="256"),
+    pytest.param("opt-1.3b", 64, 128, id="opt-1.3b-64-128"),
+    pytest.param("internlm2-1.8b", 128, 128, id="internlm2-1.8b-128-128"),
+    # a table narrower than a compute block (P = 16 at these widths)
+    pytest.param("internlm2-1.8b", 128, 4, id="internlm2-1.8b-128-4"),
+])
+def test_paged_kernel_compiles_for_v5e(one_chip, on_chip, arch, batch, nb):
+    """Decode attention over a layer-flattened 24 x 2048-block pool of
+    lane-dense rows: opt-1.3b's 32 KV heads of 64 lanes ([NB, 16, 2048])
+    and internlm2-1.8b's 8 of 128 at G = 2 ([NB, 16, 1024])."""
+    cfg = get_config(arch)
     H, K, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
     pool = _sds(one_chip, (24 * 2048, BS, K * hd), jnp.bfloat16)
     compiled = pda.paged_gqa_decode_attention.lower(
         _sds(one_chip, (batch, H, hd), jnp.bfloat16), pool, pool,
-        _sds(one_chip, (batch, 32), jnp.int32),
+        _sds(one_chip, (batch, nb), jnp.int32),
         _sds(one_chip, (batch,), jnp.int32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < MAX_TEMP_BYTES

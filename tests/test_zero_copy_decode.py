@@ -163,3 +163,26 @@ def test_paged_view_is_pytree(setup):
     assert view2.block_size == view.block_size
     assert jnp.array_equal(view2.tables, view.tables)
     assert len(jax.tree.leaves(view2.pool)) == len(jax.tree.leaves(pool.pool))
+
+
+@pytest.mark.parametrize("overlap", [False, True])
+def test_kernel_block_counters_hand_counted(setup, rules, overlap):
+    """The engine counts the pool blocks the paged kernel copies per
+    layer and the blocks of its padded table, per plain decode step.
+    Prompts of 5, 8 and 17 tokens in blocks of 8, two decode steps:
+    lengths 6, 9, 18 then 7, 10, 19 copy 1 + 2 + 3 blocks each step, of
+    a 4 x 4 table (batch and table width bucketed to powers of two)."""
+    from repro.serving.workload import Request
+    cfg, params = setup
+    engine = ContinuousBatchingEngine(
+        Model(cfg, rules), params,
+        EngineConfig(max_batch=4, block_size=8, kv_pool_tokens=1024,
+                     max_model_len=64, prefill_bucket=16, overlap=overlap))
+    reqs = [Request(i, np.arange(1, n + 1, dtype=np.int32),
+                    max_new_tokens=3) for i, n in enumerate((5, 8, 17))]
+    engine.run(reqs)
+    assert all(len(r.output_tokens) == 3 for r in reqs)
+    assert engine.kernel_live_blocks == 2 * (1 + 2 + 3)
+    assert engine.kernel_table_blocks == 2 * 4 * 4
+    engine.reset_stats()
+    assert engine.kernel_live_blocks == engine.kernel_table_blocks == 0
