@@ -1,8 +1,11 @@
-"""Operations and bytes the algorithm needs, from a config's sizes.
+"""Operations and bytes the ``decoder`` family's algorithm needs, from a
+config's sizes.
 
 Counted from shapes alone, whatever implements them: padding rows,
 padded table entries and the padded vocabulary are not work. ``s`` is the
-dict of ``bench/models/decoder.py:sizes``.
+dict of ``bench/models/decoder.py:sizes``. Every layer is the same dense
+full-attention layer: one MLP block of ``d x f`` per layer, and each layer
+reads the whole live context.
 """
 from __future__ import annotations
 

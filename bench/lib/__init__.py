@@ -1,2 +1,3 @@
-"""The benchmark's yardstick: traffic, serving loop, counts, trace
-reduction and the correctness comparison."""
+"""The benchmark's yardstick: traffic, serving loop, trace reduction and
+the correctness comparison; the operation and byte counts are each model
+family's own (``bench/counts/``)."""

@@ -22,7 +22,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-from bench.lib import check, counts, manifest, serve, traffic
+from bench.lib import check, manifest, serve, traffic
 
 
 def log(msg: str):
@@ -122,8 +122,10 @@ def end_to_end(run: serve.Run, seconds: float, setup_s: float) -> Dict:
             "setup_s": setup_s}
 
 
-def per_layer(cell, run: serve.Run, s: Dict, peaks: Dict, red,
+def per_layer(cell, counts, run: serve.Run, s: Dict, peaks: Dict, red,
               end: float) -> Dict:
+    """Every per-layer metric of the cell its reader finds; ``counts`` is
+    the cell's family's (``bench/counts/<family>.py``)."""
     ctx = types.SimpleNamespace(
         s=s, peaks=peaks, counts=counts, trace=red,
         rounds=run.window_rounds(end), window_s=end - run.t_open)
@@ -157,11 +159,12 @@ def run_cell(root: Path, argv: List[str], t_process: float,
 
     from bench.lib import program
     family = manifest.family(cell)
-    s = family.sizes(cell.config)
-    cfg = program.arch_config(cell.config, s)
+    s = family.reference.sizes(cell.config)
+    cfg = family.adapter.arch_config(cell.config, s)
     dep = cell.config["deployment"]
     key = seed_key(args.seed)
-    params = program.make_params(cfg, family, s, key)
+    params = program.make_params(cfg, family.reference, family.adapter, s,
+                                 key)
     eng, api = program.make_engine(cfg, params, dep)
     items = traffic.generate(cell.traffic, s["V"], args.seed)
     w = serve.warm_up(api, items, dep["max_batch"], eng.ecfg.block_size,
@@ -216,7 +219,7 @@ def run_cell(root: Path, argv: List[str], t_process: float,
             cell.chips)))
         shutil.rmtree(trace_dir, ignore_errors=True)
         device["busy_s"], device["window_s"] = red.busy_s, red.window_s
-        vals = per_layer(cell, run, s, peaks, red, end)
+        vals = per_layer(cell, family.counts, run, s, peaks, red, end)
         units = {m["name"]: m["unit"] for m in cell.per_layer}
         result["breakdown"] = {
             "device_ops": sorted(([n, v] for n, v in red.op_s.items()),
@@ -253,7 +256,7 @@ def run_cell(root: Path, argv: List[str], t_process: float,
         leaf.delete()
     del api, eng, run, params
     gc.collect()
-    gap, gap_ctl = check.logit_gaps(family, s, key, reqs,
+    gap, gap_ctl = check.logit_gaps(family.reference, s, key, reqs,
                                     dep["max_model_len"], control=control)
     if control:
         # the control in the program's place: its tokens are compared

@@ -1,7 +1,8 @@
 """model.mfu: operations the model needs for every prompt and output token
 the window processed, over the window's length times the chip's peak.
 
-Counted from the config's shapes (``bench/lib/counts.py``): two per
+Counted from the config's shapes by the cell's family
+(``bench/counts/<family>.py``, ``ctx.counts``): for ``decoder``, two per
 matmul weight per token, attention over each token's live context, one
 row of logits per output token; padding is not work."""
 

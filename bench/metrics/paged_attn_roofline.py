@@ -3,10 +3,12 @@ decode attention of the window's decode steps, over the device time of
 the ``paged_decode_attention`` kernel's events
 (``kernels/paged_decode_attention.py``).
 
-The bound is the larger of the bytes it must move (K and V rows of every
-live context, queries and outputs) over HBM bandwidth and its operations
-over peak compute; at G query heads per KV head it does about G
-operations per byte, far under the v5e ridge, so the bytes bound it."""
+The bound is the larger of the bytes it must move over HBM bandwidth and
+its operations over peak compute, both counted by the cell's family
+(``bench/counts/<family>.py``, ``ctx.counts``). For ``decoder`` the bytes
+are the K and V rows of every live context, queries and outputs; at G
+query heads per KV head it does about G operations per byte, far under
+the v5e ridge, so the bytes bound it."""
 
 KERNEL = "paged_decode_attention"
 

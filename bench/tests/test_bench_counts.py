@@ -5,8 +5,9 @@ import math
 
 import pytest
 
-from bench.lib import counts
+from bench.counts import decoder as counts
 from bench.models import decoder
+from bench.programs import decoder as adapter
 from bench.tests.tiny import BENCH
 
 
@@ -39,9 +40,8 @@ def test_opt_param_count_by_hand():
 @pytest.mark.parametrize("name", ["opt-1.3b", "internlm2-1.8b"])
 def test_param_count_matches_the_program(name):
     from repro.models.model import abstract_params
-    from bench.lib import program
     s = sizes(name)
-    cfg = program.arch_config(json.loads(
+    cfg = adapter.arch_config(json.loads(
         (BENCH / "configs" / f"{name}.json").read_text()), s)
     leaves = [p for p in __import__("jax").tree.leaves(
         abstract_params(cfg), is_leaf=lambda x: hasattr(x, "logical"))]

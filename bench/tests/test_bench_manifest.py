@@ -54,6 +54,17 @@ def test_cell_resolves_its_files(cell):
     for m in c.per_layer:
         assert (BENCH / "metrics" / f"{m['name']}.py").is_file()
         assert m["moves"] in names
+    fam = c.config["family"]
+    assert c.family_files == {
+        "reference": BENCH / "models" / f"{fam}.py",
+        "adapter": BENCH / "programs" / f"{fam}.py",
+        "counts": BENCH / "counts" / f"{fam}.py"}
+    f = manifest.family(c)
+    assert callable(f.reference.sizes) and callable(f.reference.forward)
+    assert callable(f.adapter.arch_config) and callable(f.adapter.to_program)
+    for fn in ("decode_flops", "prefill_flops", "paged_attn_bytes",
+               "paged_attn_flops"):
+        assert callable(getattr(f.counts, fn))
 
 
 def test_moves_are_reported_where_the_metric_is():

@@ -8,6 +8,7 @@ import pytest
 
 from bench.lib import program
 from bench.models import decoder
+from bench.programs import decoder as adapter
 
 TINY = {
     "opt": {"name": "t-opt", "registry": "opt-1.3b", "model_type": "opt",
@@ -34,9 +35,9 @@ def test_reference_matches_program_forward(fam):
     from repro.sharding import rules_for
     rules = rules_for(make_test_mesh())
     s = decoder.sizes(TINY[fam])
-    cfg = program.arch_config(TINY[fam], s)
+    cfg = adapter.arch_config(TINY[fam], s)
     key = jax.random.PRNGKey(3)
-    params = program.make_params(cfg, decoder, s, key)
+    params = program.make_params(cfg, decoder, adapter, s, key)
     tokens = jax.random.randint(jax.random.PRNGKey(4), (1, 40), 0, s["V"])
     with jax.default_matmul_precision("highest"):
         got, _ = jax.jit(lambda p, t: forward(p, cfg, rules, {"tokens": t})

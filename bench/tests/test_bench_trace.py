@@ -5,7 +5,8 @@ import types
 
 import pytest
 
-from bench.lib import counts, manifest, trace
+from bench.counts import decoder as counts
+from bench.lib import manifest, trace
 from bench.tests.tiny import BENCH, REPO
 
 TRACE = BENCH / "tests" / "data" / "opt-1.3b.chat_backlog.xplane.pb"

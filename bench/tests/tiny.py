@@ -1,6 +1,7 @@
 """A benchmark root holding one tiny cell, made only of new files: the
-code and readers of this checkout, a config, a traffic mix, a limits
-file and a manifest of their own. Small enough for the CPU."""
+code, model families and readers of this checkout, a config, a traffic
+mix, a limits file and a manifest of their own. Small enough for the
+CPU."""
 from __future__ import annotations
 
 import json
@@ -35,13 +36,19 @@ LIMITS = {"max_logit_gap": {"limit": 0.08}, "min_sampled_tokens": 60}
 
 
 def make_root(tmp: Path) -> Path:
-    """A checkout-like root whose ``bench/`` links this checkout's code
-    and readers and adds the tiny cell's own files."""
+    """A checkout-like root whose ``bench/`` links this checkout's code,
+    model families and readers and adds the tiny cell's own files. The
+    families' and readers' directories are the root's own, each file in
+    them a link, so that a test can add a family or a reader."""
     b = tmp / "bench"
     for sub in ("configs", "traffic", "cells"):
         (b / sub).mkdir(parents=True)
-    for name in ("lib", "models", "metrics", "peaks.json", "__init__.py"):
+    for name in ("lib", "peaks.json", "__init__.py"):
         (b / name).symlink_to(BENCH / name)
+    for sub in ("models", "programs", "counts", "metrics"):
+        (b / sub).mkdir()
+        for f in (BENCH / sub).glob("*.py"):
+            (b / sub / f.name).symlink_to(f)
     (b / "configs" / "tiny-opt.json").write_text(json.dumps(CONFIG))
     (b / "traffic" / "tiny.json").write_text(json.dumps(TRAFFIC))
     (b / "cells" / f"{CELL}.json").write_text(json.dumps(LIMITS))
